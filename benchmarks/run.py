@@ -63,6 +63,7 @@ import numpy as np
 sys.path.insert(0, "src")  # allow `python -m benchmarks.run` from repo root
 
 from repro.benchlib import CostModel, modeled_query_seconds  # noqa: E402
+from repro.chip import enable_compile_cache  # noqa: E402
 from repro.core import count_stars  # noqa: E402
 from repro.core.patterns import star_decomposition  # noqa: E402
 
@@ -508,7 +509,7 @@ def fig_kernels() -> None:
 
     rng = np.random.default_rng(0)
     values = jnp.asarray(np.sort(rng.integers(0, 4 * n_keys, n_keys))
-                         .astype(np.int64))
+                         .astype(np.int32))
 
     # --- run_probe: prefetch vs dense on two window shapes --------------
     # dense windows: each row's run spans ~1/8 of the column, scattered —
@@ -521,12 +522,12 @@ def fig_kernels() -> None:
     hi_dense = np.minimum(n_keys, lo_dense + rng.integers(0, n_keys // 8, n_q))
     lo_clust = np.sort(rng.integers(0, n_keys, n_q))
     hi_clust = np.minimum(n_keys, lo_clust + rng.integers(0, 64, n_q))
-    targets = jnp.asarray(rng.integers(0, 4 * n_keys, n_q).astype(np.int64))
+    targets = jnp.asarray(rng.integers(0, 4 * n_keys, n_q).astype(np.int32))
     n_v_tiles = -(-n_keys // DEFAULT_V_TILE)
     for case, lo64, hi64 in (("densewin", lo_dense, hi_dense),
                              ("clustwin", lo_clust, hi_clust)):
-        lo = jnp.asarray(lo64.astype(np.int64))
-        hi = jnp.asarray(hi64.astype(np.int64))
+        lo = jnp.asarray(lo64.astype(np.int32))
+        hi = jnp.asarray(hi64.astype(np.int32))
         pos_ref, hit_ref = ref.run_probe_ref(values, lo, hi, targets)
         # fraction of value tiles a prefetch row block actually streams
         blk_lo = (lo64 // DEFAULT_V_TILE).reshape(-1, DEFAULT_R_TILE) \
@@ -551,7 +552,7 @@ def fig_kernels() -> None:
     # --- point probe across column lengths: the calibration fit ---------
     cal_sizes = sorted({max(DEFAULT_K_TILE, n_keys // 4), n_keys // 2,
                         n_keys})
-    q_cal = jnp.asarray(rng.integers(0, 4 * n_keys, n_q).astype(np.int64))
+    q_cal = jnp.asarray(rng.integers(0, 4 * n_keys, n_q).astype(np.int32))
     passes, walls = [], []
     for size in cal_sizes:
         wall, _ = timed(lambda k, q: sorted_probe_pallas(k, q,
@@ -584,10 +585,10 @@ def fig_kernels() -> None:
     d_q64 = rng.integers(0, 4 * n_keys, n_q)
     d_q64[:n_q // 2] = np.asarray(values)[
         rng.integers(0, n_keys, n_q // 2)]  # half exact hits
-    d_q = jnp.asarray(d_q64.astype(np.int64))
+    d_q = jnp.asarray(d_q64.astype(np.int32))
     for frac in (0.01, 0.1, 0.5):
         m = max(8, int(frac * n_keys))
-        ins64 = np.sort(rng.integers(0, 4 * n_keys, m).astype(np.int64))
+        ins64 = np.sort(rng.integers(0, 4 * n_keys, m).astype(np.int32))
         tomb64 = np.sort(rng.choice(n_keys, min(m // 2, n_keys),
                                     replace=False).astype(np.int32))
         ins = jnp.asarray(ins64)
@@ -729,8 +730,8 @@ def kernels() -> None:
     dispatched = "pallas" if ops._use_pallas() else "jnp-oracle"
 
     rng = np.random.default_rng(0)
-    keys = np.sort(rng.integers(0, 3_000_000, 1_000_000)).astype(np.int64)
-    queries = rng.integers(0, 3_000_000, 4096).astype(np.int64)
+    keys = np.sort(rng.integers(0, 3_000_000, 1_000_000)).astype(np.int32)
+    queries = rng.integers(0, 3_000_000, 4096).astype(np.int32)
     kj, qj = jnp.asarray(keys), jnp.asarray(queries)
 
     r, c = ref.sorted_probe_ref(kj, qj)
@@ -884,6 +885,7 @@ def main() -> None:
         raise SystemExit(f"unknown figure(s) {unknown}; "
                          f"choose from {sorted(by_name)}")
     figs = [by_name[n] for n in selected] if selected else FIGS
+    enable_compile_cache()
     if any(f not in _STORELESS for f in figs):
         g, store = bench_graph()
         print(f"# WatDiv bench instance: {store.n_triples} triples, "

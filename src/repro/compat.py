@@ -1,15 +1,15 @@
-"""jax version compatibility shims (single home — import from here).
+"""jax API shims shared by the distributed runtime (single home).
 
-The container tracks jax 0.4.x while the code targets the current public
-API; two spellings differ:
-
-- ``shard_map``: public ``jax.shard_map`` (>= 0.6, ``check_vma`` kwarg) vs
-  ``jax.experimental.shard_map.shard_map`` (0.4/0.5, ``check_rep`` kwarg).
-  Replication checking is disabled either way: the engine's lane outputs
-  are deliberately device-varying along the lane axes.
-- ``axis_size``: ``jax.lax.axis_size`` (>= 0.6) vs ``psum(1, axis)`` —
-  both give the named-axis extent inside a mapped context (the psum of a
-  literal 1 constant-folds to the static size).
+- ``shard_map``: ``jax.shard_map`` with replication checking off — the
+  engine's lane outputs are deliberately device-varying along the lane
+  axes.
+- ``axis_size``: the named-axis extent inside a mapped context.
+- ``auto_axes``: a mesh with every axis in ``Auto`` sharding mode.
+  ``jax.make_mesh`` makes ``Explicit`` axes, whose jit outputs carry their
+  sharding in their type, and host-side indexing of such a result (the
+  scheduler pulls one lane's prefix with ``rows[j, :n]``) then demands an
+  ``out_sharding``.  The runtime places its arrays itself, so it runs on
+  ``Auto`` axes.
 """
 
 from __future__ import annotations
@@ -17,23 +17,23 @@ from __future__ import annotations
 from typing import Any, Callable
 
 import jax
+from jax.sharding import AxisType, Mesh
 
-if hasattr(jax, "shard_map"):
-    def shard_map(f: Callable, mesh: Any, in_specs: Any,
-                  out_specs: Any) -> Callable:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
 
-    def shard_map(f: Callable, mesh: Any, in_specs: Any,
-                  out_specs: Any) -> Callable:
-        return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
+def shard_map(f: Callable, mesh: Any, in_specs: Any,
+              out_specs: Any) -> Callable:
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def axis_size(axis: str):
     """Extent of a named mapped axis, inside shard_map/vmap."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    return jax.lax.psum(1, axis)
+    return jax.lax.axis_size(axis)
+
+
+def auto_axes(mesh: Mesh | None) -> Mesh | None:
+    """``mesh`` with every axis in ``Auto`` mode (``None`` passes through)."""
+    if mesh is None or all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))

@@ -51,6 +51,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from repro.compat import auto_axes
 from repro.compat import shard_map as _shard_map
 
 from repro.core.bindings import BindingTable, unit_table
@@ -241,7 +242,7 @@ class DistributedEngine:
     def __init__(self, store: TripleStore, mesh: Mesh,
                  cfg: EngineConfig, dcfg: DistConfig | None = None):
         self.store = store
-        self.mesh = mesh
+        self.mesh = mesh = auto_axes(mesh)
         self.cfg = cfg
         self.dcfg = dcfg or DistConfig()
         if self.dcfg.pod_axis and self.dcfg.pod_axis not in mesh.axis_names:
@@ -442,16 +443,16 @@ class DistributedEngine:
         # are the trace-time static the production store also presents
         # when it has no pending writes
         stacked_spec = StoreArrays(
-            key_ps_pso=_spec(shard_len, jnp.int64),
+            key_ps_pso=_spec(shard_len, jnp.int32),
             s_pso=_spec(shard_len, jnp.int32),
             o_pso=_spec(shard_len, jnp.int32),
-            key_po_pos=_spec(shard_len, jnp.int64),
+            key_po_pos=_spec(shard_len, jnp.int32),
             s_pos=_spec(shard_len, jnp.int32),
             o_pos=_spec(shard_len, jnp.int32),
-            ins_key_ps=_spec(0, jnp.int64),
+            ins_key_ps=_spec(0, jnp.int32),
             ins_s_pso=_spec(0, jnp.int32),
             ins_o_pso=_spec(0, jnp.int32),
-            ins_key_po=_spec(0, jnp.int64),
+            ins_key_po=_spec(0, jnp.int32),
             ins_s_pos=_spec(0, jnp.int32),
             ins_o_pos=_spec(0, jnp.int32),
             tomb_pos_ps=_spec(0, jnp.int32),

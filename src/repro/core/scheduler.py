@@ -106,6 +106,7 @@ import numpy as np
 from jax.sharding import Mesh
 
 from repro import faults, obs
+from repro.compat import auto_axes
 from repro.core import stepper
 from repro.core.bindings import BindingTable
 from repro.core.capacity import CapacityPlanner
@@ -345,7 +346,7 @@ class QueryScheduler:
                           registry=self.registry)
         self.planner = planner if planner is not None \
             else CapacityPlanner(store, cfg, registry=self.registry)
-        self.mesh = mesh
+        self.mesh = mesh = auto_axes(mesh)
         if mesh is not None and data_axis is not None \
                 and data_axis not in mesh.axis_names:
             data_axis = None  # a lane-only mesh: replicated/vmap picks only
@@ -717,6 +718,10 @@ class QueryScheduler:
         use_mesh = (not use_shard and self.mesh is not None
                     and B >= self._mesh_slots)
         lowering = "shard" if use_shard else "mesh" if use_mesh else "vmap"
+        # the lane layout the wave's table takes on the mesh, for the
+        # digest and replay kernels that run on it between unit steps
+        lane_layout = (self.mesh, self._shard_lane_axes) if use_shard \
+            else (self.mesh, self._lane_axes) if use_mesh else (None, ())
         slots = self._shard_slots if use_shard \
             else self._mesh_slots if use_mesh else 0
         if slots and B % slots:
@@ -821,7 +826,8 @@ class QueryScheduler:
             if scfg.use_cache:
                 csp = tr.begin("cache.probe") if tr else None
                 d = np.asarray(
-                    stepper.digest_step(io.read_cols)(rows_d, valid_d))
+                    stepper.digest_step(io.read_cols, *lane_layout)(
+                        rows_d, valid_d))
                 digs = {j: tuple(int(x) for x in d[j]) for j in active}
                 first_of: dict[tuple, int] = {}
                 for j in active:
@@ -1000,7 +1006,8 @@ class QueryScheduler:
                     faults.hit("cache.replay", sig=plan.signature, k=k)
                 psp = tr.begin("cache.replay_device",
                                lanes=len(live)) if tr else None
-                rows_d, valid_d = stepper.replay_step(io.write_cols)(
+                rows_d, valid_d = stepper.replay_step(
+                    io.write_cols, *lane_layout)(
                     rows_d, jnp.asarray(src_h), jnp.asarray(wr_h),
                     jnp.asarray(nout_h))
                 if psp:

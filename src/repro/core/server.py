@@ -44,6 +44,7 @@ from repro.core.bindings import (
     Expansion,
     compact,
     expand,
+    source_rows,
 )
 from repro.core.patterns import StarPattern
 from repro.kernels import ops as kops
@@ -164,8 +165,16 @@ def _term_values(rows: jnp.ndarray, src: tuple[str, int],
                  const_vec: jnp.ndarray) -> jnp.ndarray:
     kind, idx = src
     if kind == "const":
-        return jnp.broadcast_to(const_vec[idx], (rows.shape[0],))
-    return rows[:, idx].astype(jnp.int64)
+        return jnp.broadcast_to(const_vec[idx].astype(jnp.int32),
+                                (rows.shape[0],))
+    return rows[:, idx]
+
+
+def _composite_key(ctx: EvalCtx, b: BranchPlan,
+                   term: jnp.ndarray) -> jnp.ndarray:
+    """int32 probe key ``p * radix + term`` (the store's key layout;
+    ``rdf.store.check_key_radix`` keeps it inside int32)."""
+    return ctx.const_vec[b.pred_ci].astype(jnp.int32) * ctx.radix + term
 
 
 def _active(table: BindingTable) -> jnp.ndarray:
@@ -197,7 +206,7 @@ def _probe_run(ctx: EvalCtx, b: BranchPlan, table: BindingTable
     triples, so a non-owned row's key misses the insert run too — owner
     masking needs no delta-side mask pass."""
     s_vals = _term_values(table.rows, b.subj_src, ctx.const_vec)
-    key = ctx.const_vec[b.pred_ci] * ctx.radix + s_vals
+    key = _composite_key(ctx, b, s_vals)
     if ctx.owner is None:
         lo, hi = kops.eqrange(ctx.dev.key_ps_pso, key)
         return lo, hi, None, key
@@ -309,8 +318,7 @@ def _merged_expand(ctx: EvalCtx, table: BindingTable, lo: jnp.ndarray,
     # side A: live base elements
     cum_l = jnp.cumsum(deg_live)
     starts_l = cum_l - deg_live
-    src_a = jnp.clip(kops.searchsorted(cum_l, j, side="right"), 0,
-                     n_rows - 1)
+    src_a = jnp.clip(source_rows(cum_l, cap), 0, n_rows - 1)
     r_a = j - starts_l[src_a]
     valid_a = j < cum_l[-1]
     k_glob = (lo[src_a] - tlo[src_a]).astype(jnp.int64) + r_a
@@ -321,7 +329,7 @@ def _merged_expand(ctx: EvalCtx, table: BindingTable, lo: jnp.ndarray,
     else:
         q = k_glob
     q = jnp.clip(q, 0, max(nb - 1, 0))
-    xs = [bc[q].astype(jnp.int64) for bc, _ in cols]
+    xs = [bc[q] for bc, _ in cols]
     if m:
         if len(cols) == 1:
             c_a = _run_rank(cols[0][1], ilo[src_a], ihi[src_a], xs[0])
@@ -340,12 +348,11 @@ def _merged_expand(ctx: EvalCtx, table: BindingTable, lo: jnp.ndarray,
     if m:
         cum_i = jnp.cumsum(deg_ins)
         starts_i = cum_i - deg_ins
-        src_b = jnp.clip(kops.searchsorted(cum_i, j, side="right"), 0,
-                         n_rows - 1)
+        src_b = jnp.clip(source_rows(cum_i, cap), 0, n_rows - 1)
         r_b = j - starts_i[src_b]
         valid_b = j < cum_i[-1]
         flat = jnp.clip(ilo[src_b].astype(jnp.int64) + r_b, 0, m - 1)
-        bs = [ic[flat].astype(jnp.int64) for _, ic in cols]
+        bs = [ic[flat] for _, ic in cols]
         if len(cols) == 1:
             p_b = _run_rank(cols[0][0], lo[src_b], hi[src_b], bs[0])
         else:
@@ -442,7 +449,7 @@ def scan_obound(ctx: EvalCtx, b: BranchPlan, table: BindingTable
     delta when one is overlaid)."""
     active = _active(table)
     o_vals = _term_values(table.rows, b.obj_src, ctx.const_vec)
-    key = ctx.const_vec[b.pred_ci] * ctx.radix + o_vals
+    key = _composite_key(ctx, b, o_vals)
     lo, hi = kops.eqrange(ctx.dev.key_po_pos, key)
     if not _has_delta(ctx.dev):
         ex = expand(lo, hi, table.valid, table.cap)
@@ -467,7 +474,7 @@ def scan_ovar_free(ctx: EvalCtx, b: BranchPlan, table: BindingTable
     pair (both sides are (s, o)-lex within the predicate run).
     """
     active = _active(table)
-    p = ctx.const_vec[b.pred_ci]
+    p = ctx.const_vec[b.pred_ci].astype(jnp.int32)
     qk = jnp.stack([p * ctx.radix, (p + 1) * ctx.radix])
     bounds, _ = kops.eqrange(ctx.dev.key_ps_pso, qk)
     lo = jnp.broadcast_to(bounds[0], table.valid.shape)

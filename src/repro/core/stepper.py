@@ -74,7 +74,9 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
+from jax.sharding import PartitionSpec as P
 
+from repro.compat import shard_map
 from repro.core.bindings import BindingTable
 from repro.core.distributed import make_batch_step
 from repro.core.server import (
@@ -579,27 +581,46 @@ def serial_unit_step(up: UnitPlan, radix: int, logn: int | None = None):
     return step
 
 
-def digest_step(read_cols: tuple[int, ...]):
+def _on_lanes(fn: Callable, n_args: int, n_out: int, mesh: Mesh | None,
+              lane_axes: tuple[str, ...]) -> Callable:
+    """``fn`` over a wave's lanes, jitted.  A mesh wave's table comes back
+    from its unit step with the lanes split along ``lane_axes`` and
+    replicated over the other mesh axes; ``fn`` then runs per device in
+    that layout, inside ``shard_map``: XLA cannot partition a Mosaic
+    kernel by itself."""
+    if mesh is None:
+        return jax.jit(fn)
+    spec = P() if not lane_axes else \
+        P(lane_axes if len(lane_axes) > 1 else lane_axes[0])
+    return jax.jit(shard_map(fn, mesh, (spec,) * n_args,
+                             spec if n_out == 1 else (spec,) * n_out))
+
+
+def digest_step(read_cols: tuple[int, ...], mesh: Mesh | None = None,
+                lane_axes: tuple[str, ...] = ()):
     """Jitted wave fingerprint: ``(rows[B, cap, V], valid[B, cap]) ->
     uint32[B, 4]`` digests of each lane's valid prefix restricted to
     ``read_cols`` — the device half of the digest-first cache keys
-    (host twin: ``ref.fingerprint_prefix_np`` on replayed state)."""
-    key = ("digest", read_cols, kops.FORCE, kops.BREAKER.generation)
+    (host twin: ``ref.fingerprint_prefix_np`` on replayed state).
+    ``mesh``/``lane_axes``: the lane layout of a mesh wave (see
+    ``_on_lanes``)."""
+    key = ("digest", read_cols, kops.FORCE, kops.BREAKER.generation, mesh,
+           lane_axes)
     fn = _STEP_CACHE.get(key)
     if fn is None:
         cols = jnp.asarray(read_cols, jnp.int32) if read_cols else None
 
-        @jax.jit
-        def fn(rows, valid):
+        def digest(rows, valid):
             block = jnp.take(rows, cols, axis=2) if cols is not None \
                 else rows[:, :, :0]
             return jax.vmap(kops.fingerprint_rows)(block, valid)
 
-        _STEP_CACHE[key] = fn
+        fn = _STEP_CACHE[key] = _on_lanes(digest, 2, 1, mesh, lane_axes)
     return fn
 
 
-def replay_step(write_cols: tuple[int, ...]):
+def replay_step(write_cols: tuple[int, ...], mesh: Mesh | None = None,
+                lane_axes: tuple[str, ...] = ()):
     """Jitted wave-wide device-side cache-hit replay.
 
     ``(rows[B, cap, V], src[B, M], written[B, M, W], n_out[B]) ->
@@ -609,21 +630,21 @@ def replay_step(write_cols: tuple[int, ...]):
     twin ``fragcache.replay``).  Lanes with ``n_out == 0`` (padding,
     retired, negative fragments) come out empty.  This is what keeps
     all-hit waves off the host: the uploaded delta is the small object,
-    the Omega block never moves.
+    the Omega block never moves.  ``mesh``/``lane_axes``: the lane layout
+    of a mesh wave (see ``_on_lanes``).
     """
     key = ("replay", tuple(write_cols), kops.FORCE,
-           kops.BREAKER.generation)
+           kops.BREAKER.generation, mesh, lane_axes)
     fn = _STEP_CACHE.get(key)
     if fn is None:
         cols = tuple(write_cols)
 
-        @jax.jit
-        def fn(rows, src, written, n_out):
+        def replay(rows, src, written, n_out):
             return jax.vmap(
                 lambda r, s, w, n: kops.replay_delta(r, s, w, n, cols)
             )(rows, src, written, n_out)
 
-        _STEP_CACHE[key] = fn
+        fn = _STEP_CACHE[key] = _on_lanes(replay, 4, 2, mesh, lane_axes)
     return fn
 
 

@@ -15,10 +15,11 @@ the query batch through the VPU, not by the column stream — one kernel
 launch per dispatched probe keeps the delta overhead at
 O(delta / K_TILE) tile passes instead of four separate launches.
 
-Both delta columns stream through the same k-tile grid axis (padded to a
-common tiled length; the insert column is int64 keys, the tombstone
-column int32 base positions widened to int64 lanes), and each query tile
-accumulates
+Both delta columns stream through the same k-tile grid axis as
+lane-dense ``(1, K_TILE)`` rows (padded to a common tiled length; on the
+served path both are int32 — insert composite keys and base positions),
+the per-query operands sit in ``(Q_TILE, 1)`` columns (``kernels.layout``),
+and each query tile accumulates
 
     ins_lo[i]  = #{k in ins_keys  : k <  query_keys[i]}
     ins_hi[i]  = #{k in ins_keys  : k <= query_keys[i]}
@@ -26,8 +27,8 @@ accumulates
     tomb_hi[i] = #{p in tomb_pos  : p <  base_hi[i]}
 
 across k-tile steps (init at j == 0), exactly the ``sorted_probe``
-accumulation scheme.  Padding: insert keys pad with int64 max (invisible
-below the dtype max; the wrapper clamps ``ins_hi`` like ``sorted_probe``
+accumulation scheme.  Padding: insert keys pad with the key dtype's max
+(invisible below it; the wrapper clamps ``ins_hi`` like ``sorted_probe``
 does), tombstone positions pad with int32 max (base positions are always
 ``<= n_base < int32 max``, and both tombstone ranks use strict ``<``, so
 the padding is never counted — no clamp needed).
@@ -41,37 +42,41 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.layout import ZERO, as_col
+
 DEFAULT_Q_TILE = 256
 DEFAULT_K_TILE = 2048
+
+
+def _count(col, qs, strict: bool):
+    """int32 ``#{col < qs}`` (or ``<=``) per query row: [Q_TILE, 1]."""
+    hit = (col < qs) if strict else (col <= qs)
+    return jnp.sum(hit.astype(jnp.int32), axis=1, keepdims=True,
+                   dtype=jnp.int32)
 
 
 def _delta_probe_kernel(ins_ref, tomb_ref, qkey_ref, qlo_ref, qhi_ref,
                         ins_lo_ref, ins_hi_ref, tomb_lo_ref, tomb_hi_ref):
     j = pl.program_id(1)
-    ins = ins_ref[...]  # int64[K_TILE]
-    tomb = tomb_ref[...]  # int64[K_TILE] (widened base positions)
-    qk = qkey_ref[...]  # int64[Q_TILE]
-    ql = qlo_ref[...].astype(jnp.int64)  # [Q_TILE]
-    qh = qhi_ref[...].astype(jnp.int64)
-
-    p_ilo = jnp.sum(ins[None, :] < qk[:, None], axis=1, dtype=jnp.int32)
-    p_ihi = jnp.sum(ins[None, :] <= qk[:, None], axis=1, dtype=jnp.int32)
-    p_tlo = jnp.sum(tomb[None, :] < ql[:, None], axis=1, dtype=jnp.int32)
-    p_thi = jnp.sum(tomb[None, :] < qh[:, None], axis=1, dtype=jnp.int32)
+    ins = ins_ref[...]  # [1, K_TILE] insert keys
+    tomb = tomb_ref[...]  # [1, K_TILE] tombstoned base positions
+    qk = qkey_ref[...]  # [Q_TILE, 1]
+    ql = qlo_ref[...]
+    qh = qhi_ref[...]
+    partials = ((ins_lo_ref, _count(ins, qk, True)),
+                (ins_hi_ref, _count(ins, qk, False)),
+                (tomb_lo_ref, _count(tomb, ql, True)),
+                (tomb_hi_ref, _count(tomb, qh, True)))
 
     @pl.when(j == 0)
     def _init():
-        ins_lo_ref[...] = p_ilo
-        ins_hi_ref[...] = p_ihi
-        tomb_lo_ref[...] = p_tlo
-        tomb_hi_ref[...] = p_thi
+        for ref, part in partials:
+            ref[...] = part
 
     @pl.when(j != 0)
     def _accum():
-        ins_lo_ref[...] = ins_lo_ref[...] + p_ilo
-        ins_hi_ref[...] = ins_hi_ref[...] + p_ihi
-        tomb_lo_ref[...] = tomb_lo_ref[...] + p_tlo
-        tomb_hi_ref[...] = tomb_hi_ref[...] + p_thi
+        for ref, part in partials:
+            ref[...] = ref[...] + part
 
 
 @functools.partial(jax.jit, static_argnames=("q_tile", "k_tile", "interpret"))
@@ -85,45 +90,43 @@ def delta_probe_pallas(ins_keys: jnp.ndarray, tomb_pos: jnp.ndarray,
                                   jnp.ndarray, jnp.ndarray]:
     """Fused delta probe: insert eqrange + tombstone ranks of the base run.
 
-    ``ins_keys`` sorted int64 (insert composite keys), ``tomb_pos`` sorted
-    int32 (tombstoned base positions), ``query_keys`` the probe keys the
-    base eqrange just served, ``base_lo``/``base_hi`` that eqrange's
-    result.  Returns ``(ins_lo, ins_hi, tomb_lo, tomb_hi)`` int32 — see
-    the module docstring for the definitions.
+    ``ins_keys`` sorted insert composite keys, ``tomb_pos`` sorted int32
+    tombstoned base positions, ``query_keys`` the probe keys the base
+    eqrange just served, ``base_lo``/``base_hi`` that eqrange's result.
+    Returns ``(ins_lo, ins_hi, tomb_lo, tomb_hi)`` int32 — see the module
+    docstring for the definitions.  Keys of another dtype than int32
+    (only the interpret-mode tests pass them) widen every lane to it.
     """
     m = ins_keys.shape[0]
     t = tomb_pos.shape[0]
     q = query_keys.shape[0]
-    maxkey = jnp.iinfo(ins_keys.dtype).max
+    dt = jnp.promote_types(ins_keys.dtype, query_keys.dtype)
+    maxkey = jnp.iinfo(dt).max
     k_len = max(m, t, 1)
     k_len += -k_len % k_tile
-    ins_p = jnp.pad(ins_keys, (0, k_len - m), constant_values=maxkey)
+    # both columns pad to the common length k_len (an empty one included)
+    ins_p = jnp.pad(ins_keys.astype(dt), (0, k_len - m),
+                    constant_values=maxkey).reshape(1, -1)
     tomb_p = jnp.pad(tomb_pos, (0, k_len - t),
                      constant_values=jnp.iinfo(tomb_pos.dtype).max)
-    tomb_p = tomb_p.astype(jnp.int64)
-    q_pad = -q % q_tile
-    qk_p = jnp.pad(query_keys, (0, q_pad), constant_values=maxkey)
-    ql_p = jnp.pad(base_lo, (0, q_pad))
-    qh_p = jnp.pad(base_hi, (0, q_pad))
+    tomb_p = tomb_p.astype(dt).reshape(1, -1)
+    qk_p = as_col(query_keys.astype(dt), q_tile, maxkey)
+    ql_p = as_col(base_lo.astype(dt), q_tile, 0)
+    qh_p = as_col(base_hi.astype(dt), q_tile, 0)
+    q_len = qk_p.shape[0]
 
-    grid = (qk_p.shape[0] // q_tile, k_len // k_tile)
+    grid = (q_len // q_tile, k_len // k_tile)
+    row = pl.BlockSpec((1, k_tile), lambda i, j: (ZERO, j))
+    col = pl.BlockSpec((q_tile, 1), lambda i, j: (i, ZERO))
     out = pl.pallas_call(
         _delta_probe_kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((k_tile,), lambda i, j: (j,)),
-            pl.BlockSpec((k_tile,), lambda i, j: (j,)),
-            pl.BlockSpec((q_tile,), lambda i, j: (i,)),
-            pl.BlockSpec((q_tile,), lambda i, j: (i,)),
-            pl.BlockSpec((q_tile,), lambda i, j: (i,)),
-        ],
-        out_specs=[pl.BlockSpec((q_tile,), lambda i, j: (i,))
-                   for _ in range(4)],
-        out_shape=[jax.ShapeDtypeStruct((qk_p.shape[0],), jnp.int32)
-                   for _ in range(4)],
+        in_specs=[row, row, col, col, col],
+        out_specs=[col] * 4,
+        out_shape=[jax.ShapeDtypeStruct((q_len, 1), jnp.int32)] * 4,
         interpret=interpret,
     )(ins_p, tomb_p, qk_p, ql_p, qh_p)
-    ins_lo, ins_hi, tomb_lo, tomb_hi = (o[:q] for o in out)
+    ins_lo, ins_hi, tomb_lo, tomb_hi = (o[:q, 0] for o in out)
     # a query key equal to the dtype max sees the key padding in `<=`;
     # its true right-rank is m (same correction as sorted_probe)
     ins_hi = jnp.minimum(ins_hi, m)

@@ -17,14 +17,19 @@ implementations that must stay bit-identical:
 - ``fingerprint_rows_pallas``    — this kernel (TPU fast path),
 - ``ref.fingerprint_prefix_np``  — numpy twin for host-replayed wave state.
 
-TPU adaptation: rows stream through VMEM in 1-D tiles (same idiom as
-``run_probe``); per tile the VPU computes each row's column-folded hash,
-mixes in the global row position, masks invalid rows, and accumulates four
-salted wrapping-uint32 sums into the output block across grid steps
-(init at tile 0).  The per-salt totals are finalized (n-mix) by the
-wrapper outside the kernel so the oracle and kernel share the exact same
-tail arithmetic.  All arithmetic is uint32: TPU has no 64-bit integer
-multiply, and uint32 wrap-around is identical across numpy/jnp/Mosaic.
+TPU adaptation: the block streams through VMEM *transposed* — a
+``(C, R_TILE)`` tile, so each column is a lane-dense row and every per-row
+hash step is a plain elementwise op on ``(1, R_TILE)`` vectors.  Per tile
+the VPU computes each row's column-folded hash, mixes in the global row
+position, masks invalid rows, and adds four salted per-lane partials into
+a ``(4, R_TILE)`` output block across grid steps (init at tile 0).  The
+wrapper sums the lanes and finalizes (n-mix) outside the kernel, so the
+oracle and kernel share the exact same tail arithmetic.
+
+The kernel computes in **int32** with logical shifts: Mosaic refuses
+reductions over unsigned integers and every 64-bit type, while int32
+wrap-around multiply and add give the same bits as the uint32 spec — the
+wrapper bitcasts the partials back to uint32 before the lane sum.
 """
 
 from __future__ import annotations
@@ -35,36 +40,47 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.ref import _FP_COL, _FP_POS, _FP_SALTS, _FP_SEED, _M32
+from repro.kernels.layout import ZERO
+from repro.kernels.ref import (_FP_COL, _FP_POS, _FP_SALTS, _FP_SEED, _M32,
+                               _mix32_jnp)
 
-DEFAULT_R_TILE = 512
+DEFAULT_R_TILE = 1024
 
 
-def _mix32(x):
-    x = x ^ (x >> jnp.uint32(16))
-    x = x * jnp.uint32(0x85EBCA6B)
-    x = x ^ (x >> jnp.uint32(13))
-    x = x * jnp.uint32(0xC2B2AE35)
-    x = x ^ (x >> jnp.uint32(16))
+def _i32(c: int) -> int:
+    """A uint32 constant as the int32 with the same bits (a Python int, so
+    it stays a weak-typed literal inside the kernel)."""
+    c &= _M32
+    return c - (1 << 32) if c >= 1 << 31 else c
+
+
+def _mix32_i32(x):
+    """``ref._mix32_jnp`` on int32 bit patterns (logical shifts)."""
+    def shr(v, k):
+        return jax.lax.shift_right_logical(v, jnp.full_like(v, k))
+
+    x = x ^ shr(x, 16)
+    x = x * _i32(0x85EBCA6B)
+    x = x ^ shr(x, 13)
+    x = x * _i32(0xC2B2AE35)
+    x = x ^ shr(x, 16)
     return x
 
 
 def _fp_kernel(block_ref, mask_ref, acc_ref, *, n_cols: int):
     i = pl.program_id(0)
-    r_tile = mask_ref.shape[0]
-    h = jnp.full((r_tile,), _FP_SEED, jnp.uint32)
+    r_tile = mask_ref.shape[1]
+    h = jnp.full((1, r_tile), _i32(_FP_SEED), jnp.int32)
     for c in range(n_cols):  # static unroll: n_cols is a trace constant
-        v = block_ref[:, c].astype(jnp.uint32)
-        h = _mix32(h ^ (v + jnp.uint32(((c + 1) * _FP_COL) & _M32)))
+        v = block_ref[c:c + 1, :]  # [1, R_TILE]: column c of the rows
+        h = _mix32_i32(h ^ (v + _i32((c + 1) * _FP_COL)))
     # global row index per lane (2D iota: TPU rejects 1D)
-    local = jax.lax.broadcasted_iota(jnp.uint32, (r_tile, 1), 0)[:, 0]
-    pos = ((i * r_tile).astype(jnp.uint32) + local + jnp.uint32(1)) \
-        * jnp.uint32(_FP_POS)
-    g = _mix32(h ^ _mix32(pos))
+    local = jax.lax.broadcasted_iota(jnp.int32, (1, r_tile), 1)
+    pos = (i * r_tile + local + 1) * _i32(_FP_POS)
+    g = _mix32_i32(h ^ _mix32_i32(pos))
     m = mask_ref[...]
-    partial = jnp.stack(
-        [jnp.sum(_mix32(g + jnp.uint32(s)) * m, dtype=jnp.uint32)
-         for s in _FP_SALTS])
+    partial = jnp.concatenate(
+        [_mix32_i32(g + _i32(s)) * m for s in _FP_SALTS], axis=0)
 
     @pl.when(i == 0)
     def _init():
@@ -93,21 +109,23 @@ def fingerprint_rows_pallas(block: jnp.ndarray, valid: jnp.ndarray,
         raise ValueError("fingerprint_rows_pallas needs >= 1 column; "
                          "the dispatch layer routes 0-column blocks to ref")
     r_pad = -n % r_tile
-    block_p = jnp.pad(block, ((0, r_pad), (0, 0)))
-    mask_p = jnp.pad(valid.astype(jnp.uint32), (0, r_pad))
-    grid = (block_p.shape[0] // r_tile,)
+    block_t = jnp.pad(block.astype(jnp.int32), ((0, r_pad), (0, 0))).T
+    mask_p = jnp.pad(valid.astype(jnp.int32), (0, r_pad)).reshape(1, -1)
+    grid = (block_t.shape[1] // r_tile,)
     acc = pl.pallas_call(
         functools.partial(_fp_kernel, n_cols=n_cols),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((r_tile, n_cols), lambda i: (i, 0)),
-            pl.BlockSpec((r_tile,), lambda i: (i,)),
+            pl.BlockSpec((n_cols, r_tile), lambda i: (ZERO, i)),
+            pl.BlockSpec((1, r_tile), lambda i: (ZERO, i)),
         ],
-        out_specs=pl.BlockSpec((4,), lambda i: (0,)),
-        out_shape=jax.ShapeDtypeStruct((4,), jnp.uint32),
+        out_specs=pl.BlockSpec((4, r_tile), lambda i: (ZERO, ZERO)),
+        out_shape=jax.ShapeDtypeStruct((4, r_tile), jnp.int32),
         interpret=interpret,
-    )(block_p, mask_p)
+    )(block_t, mask_p)
+    acc = jnp.sum(jax.lax.bitcast_convert_type(acc, jnp.uint32), axis=1,
+                  dtype=jnp.uint32)
     # shared finalize (identical to the oracle's tail)
     n_in = jnp.sum(valid.astype(jnp.uint32), dtype=jnp.uint32)
     salts = jnp.asarray(_FP_SALTS, jnp.uint32)
-    return _mix32(acc ^ (n_in * jnp.uint32(_FP_POS) + salts))
+    return _mix32_jnp(acc ^ (n_in * jnp.uint32(_FP_POS) + salts))

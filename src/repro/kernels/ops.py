@@ -11,9 +11,12 @@ Dispatch policy
 ---------------
 The Pallas kernels target TPU.  Each wrapper picks the Pallas path when the
 default JAX backend is TPU and the pure-jnp oracle (``repro.kernels.ref``)
-elsewhere, so smoke tests and CPU benches stay fast while the TPU lowering
-is exercised by the dry-run.  On a non-TPU backend a forced Pallas path
-runs in ``interpret=True`` mode (kernel-body semantics, no Mosaic).
+elsewhere, so CPU tests and benches stay fast while the TPU lowering is
+exercised by ``tests/test_chip_compile.py`` (AOT compiles for a described
+v5e chip) and ``chip_smoke.py`` (on the chip).  On a non-TPU backend a
+forced Pallas path runs in ``interpret=True`` mode (kernel-body semantics,
+no Mosaic); on a TPU a kernel never runs interpreted.  Every kernel takes
+int32 operands on the served path — Mosaic has no 64-bit vectors.
 
 Set ``repro.kernels.ops.FORCE`` to ``"pallas"`` / ``"ref"`` to override:
 
@@ -90,6 +93,7 @@ Join/probe primitives (the SPF server's hot path)
 
 from __future__ import annotations
 
+import logging
 import math
 
 import jax
@@ -105,6 +109,8 @@ from repro.kernels.run_probe import (
     run_probe_prefetch_pallas,
 )
 from repro.kernels.sorted_probe import sorted_probe_pallas
+
+log = logging.getLogger(__name__)
 
 FORCE: str | None = None  # None | "pallas" | "ref"
 
@@ -216,12 +222,22 @@ class KernelBreaker:
 BREAKER = KernelBreaker()
 
 
+#: Primitives whose Pallas fault has already been logged (once each).
+_LOGGED_FAULTS: set[str] = set()
+
+
 def _guarded(prim: str, pallas_fn, ref_fn):
     """Run ``pallas_fn`` under the breaker, falling back to ``ref_fn``.
 
     The ``kernel`` fault seam fires *inside* the try: an injected kernel
     fault is indistinguishable from a real trace-time failure, so the
     chaos suite exercises exactly the production fallback path.
+
+    A fallback is never silent: the first fault of each primitive is
+    logged with its exception (a kernel the TPU compiler refuses would
+    otherwise quietly become the jnp oracle), and every oracle-served
+    call is counted as ``kernels.dispatch.<prim>.breaker_ref`` when obs
+    is on — ``chip_smoke.py`` fails on any.
     """
     if not BREAKER.allow(prim):
         _note(prim, "breaker_ref")
@@ -231,6 +247,10 @@ def _guarded(prim: str, pallas_fn, ref_fn):
             faults.hit("kernel", prim=prim)
         out = pallas_fn()
     except Exception:
+        if prim not in _LOGGED_FAULTS:
+            _LOGGED_FAULTS.add(prim)
+            log.warning("Pallas %s failed; serving the jnp oracle instead",
+                        prim, exc_info=True)
         BREAKER.record_fault(prim)
         _note(prim, "breaker_ref")
         return ref_fn()

@@ -22,8 +22,8 @@ has no 64-bit integer lanes: 32x32->64 multiplies via 16-bit halves,
 shifts carried across the limb boundary, and the final ``mod n_shards``
 folded limb-wise (``2**32 mod m`` is a trace-time constant; ``m <= 4096``
 keeps the fold inside uint32).  Bit-exact against the uint64 reference
-for int32/int64 subjects including negatives and dtype extremes — pinned
-by the kernel parity tests.
+for int32 subjects (the store's term ids) including negatives — pinned by
+the kernel parity tests.
 
 ``my_shard`` is a *traced* scalar (``jax.lax.axis_index`` under
 shard_map), so it rides in as a scalar-prefetch operand
@@ -37,9 +37,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.layout import ZERO, as_col, as_row
 from repro.kernels.sorted_probe import DEFAULT_K_TILE, DEFAULT_Q_TILE
 
 # the limb-wise fold of ``mod n_shards`` computes
@@ -91,33 +93,48 @@ def shard_of_limbs(s_lo, s_hi, n_shards: int):
     lo, hi = _mul64(lo, hi, 0x133111EB, 0x94D049BB)
     lo, hi = _xorshr(lo, hi, 31)
     hi = hi & 0x7FFFFFFF
-    r32 = (1 << 32) % n_shards
-    folded = (hi % n_shards) * r32 + (lo % n_shards)
-    return (folded % n_shards).astype(jnp.int32)
+    m = np.uint32(n_shards)
+    r32 = np.uint32((1 << 32) % n_shards)
+    # lax.rem, not ``%``: jnp's floor-mod wraps the remainder in int64
+    # sign fix-ups under x64, which Mosaic cannot lower
+    folded = jax.lax.rem(hi, m) * r32 + jax.lax.rem(lo, m)
+    return jax.lax.rem(folded, m).astype(jnp.int32)
+
+
+def _subject_limbs(subjects: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """uint32 ``(lo, hi)`` limbs of int32 ``subjects`` as the reference's
+    uint64: ``lo`` is the bit pattern, ``hi`` the sign extension (all ones
+    for a negative id) — no 64-bit op is emitted."""
+    if subjects.dtype != jnp.int32:
+        raise TypeError(f"owned_probe takes int32 subjects (the store's "
+                        f"term ids), got {subjects.dtype}")
+    lo = jax.lax.bitcast_convert_type(subjects, jnp.uint32)
+    hi = jnp.where(subjects < 0, jnp.uint32(0xFFFFFFFF), jnp.uint32(0))
+    return lo, hi
 
 
 def _owned_probe_kernel(shard_ref, s_lo_ref, s_hi_ref, keys_ref, queries_ref,
                         rank_lo_ref, rank_hi_ref, owned_ref, *,
                         n_shards: int):
     j = pl.program_id(1)
-    keys = keys_ref[...]  # [K_TILE]
-    qs = queries_ref[...]  # [Q_TILE]
+    keys = keys_ref[...]  # [1, K_TILE]
+    qs = queries_ref[...]  # [Q_TILE, 1]
     owned = shard_of_limbs(s_lo_ref[...], s_hi_ref[...],
                            n_shards) == shard_ref[0]
 
-    lt = keys[None, :] < qs[:, None]
-    le = keys[None, :] <= qs[:, None]
-    partial_lo = jnp.sum(lt, axis=1, dtype=jnp.int32)
+    partial_lo = jnp.sum((keys < qs).astype(jnp.int32), axis=1,
+                         keepdims=True, dtype=jnp.int32)
     # non-owned rows accumulate the LEFT rank on both sides: their final
     # hi lands exactly on lo — the empty run — with no post-pass mask
-    partial_hi = jnp.where(owned, jnp.sum(le, axis=1, dtype=jnp.int32),
-                           partial_lo)
+    partial_hi = jnp.where(
+        owned, jnp.sum((keys <= qs).astype(jnp.int32), axis=1,
+                       keepdims=True, dtype=jnp.int32), partial_lo)
 
     @pl.when(j == 0)
     def _init():
         rank_lo_ref[...] = partial_lo
         rank_hi_ref[...] = partial_hi
-        owned_ref[...] = owned
+        owned_ref[...] = owned.astype(jnp.int32)
 
     @pl.when(j != 0)
     def _accum():
@@ -151,41 +168,34 @@ def eqrange_owned_pallas(keys: jnp.ndarray, query_keys: jnp.ndarray,
                          f"got {n_shards}")
     n = keys.shape[0]
     q = query_keys.shape[0]
-    maxval = jnp.iinfo(keys.dtype).max
-    q_pad = -q % q_tile
-    keys_p = jnp.pad(keys, (0, -n % k_tile), constant_values=maxval)
-    queries_p = jnp.pad(query_keys, (0, q_pad), constant_values=maxval)
-    u = subjects.astype(jnp.uint64)
-    s_lo = jnp.pad((u & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32),
-                   (0, q_pad))
-    s_hi = jnp.pad((u >> jnp.uint64(32)).astype(jnp.uint32), (0, q_pad))
+    dt = jnp.promote_types(keys.dtype, query_keys.dtype)
+    maxval = jnp.iinfo(dt).max
+    keys_p = as_row(keys.astype(dt), k_tile, maxval)
+    queries_p = as_col(query_keys.astype(dt), q_tile, maxval)
+    s_lo, s_hi = _subject_limbs(subjects)
+    s_lo = as_col(s_lo, q_tile, 0)
+    s_hi = as_col(s_hi, q_tile, 0)
     shard = jnp.asarray(my_shard, jnp.int32).reshape((1,))
+    q_len = queries_p.shape[0]
 
+    col = pl.BlockSpec((q_tile, 1), lambda i, j, s: (i, ZERO))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(queries_p.shape[0] // q_tile, keys_p.shape[0] // k_tile),
+        grid=(q_len // q_tile, keys_p.shape[1] // k_tile),
         in_specs=[
-            pl.BlockSpec((q_tile,), lambda i, j, s: (i,)),  # s_lo
-            pl.BlockSpec((q_tile,), lambda i, j, s: (i,)),  # s_hi
-            pl.BlockSpec((k_tile,), lambda i, j, s: (j,)),  # keys
-            pl.BlockSpec((q_tile,), lambda i, j, s: (i,)),  # queries
+            col,  # s_lo
+            col,  # s_hi
+            pl.BlockSpec((1, k_tile), lambda i, j, s: (ZERO, j)),  # keys
+            col,  # queries
         ],
-        out_specs=[
-            pl.BlockSpec((q_tile,), lambda i, j, s: (i,)),
-            pl.BlockSpec((q_tile,), lambda i, j, s: (i,)),
-            pl.BlockSpec((q_tile,), lambda i, j, s: (i,)),
-        ],
+        out_specs=[col, col, col],
     )
     rank_lo, rank_hi, owned = pl.pallas_call(
         functools.partial(_owned_probe_kernel, n_shards=n_shards),
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((queries_p.shape[0],), jnp.int32),
-            jax.ShapeDtypeStruct((queries_p.shape[0],), jnp.int32),
-            jax.ShapeDtypeStruct((queries_p.shape[0],), jnp.bool_),
-        ],
+        out_shape=[jax.ShapeDtypeStruct((q_len, 1), jnp.int32)] * 3,
         interpret=interpret,
     )(shard, s_lo, s_hi, keys_p, queries_p)
-    rank_lo, rank_hi, owned = rank_lo[:q], rank_hi[:q], owned[:q]
+    rank_lo, rank_hi, owned = rank_lo[:q, 0], rank_hi[:q, 0], owned[:q, 0]
     rank_hi = jnp.minimum(rank_hi, n)
-    return rank_lo, rank_hi, owned
+    return rank_lo, rank_hi, owned != 0

@@ -70,13 +70,15 @@ def rank_ref(sorted_keys: jnp.ndarray, queries: jnp.ndarray,
              side: str = "left") -> jnp.ndarray:
     """One-sided rank (``searchsorted``) of ``queries`` in a sorted array.
 
-    ``method="sort"``: the default scan lowering triggers pathological XLA
-    constant folding when ``queries`` is a compile-time constant (e.g. the
-    arange of ``bindings.expand``'s ragged-expansion bookkeeping, this
-    oracle's main caller).
+    The scan lowering, behind an optimization barrier: when ``queries`` is
+    a compile-time constant (the arange of ``bindings.source_rows``, this
+    oracle's main caller) XLA would otherwise try to constant-fold the
+    search.  ``method="sort"`` avoids that too, but the TPU compiler spends
+    ~30 s on each such sort.
     """
-    return jnp.searchsorted(sorted_keys, queries, side=side,
-                            method="sort").astype(jnp.int32)
+    return jnp.searchsorted(sorted_keys,
+                            jax.lax.optimization_barrier(queries),
+                            side=side, method="scan").astype(jnp.int32)
 
 
 def subject_shard_ref(subjects: jnp.ndarray, n_shards: int) -> jnp.ndarray:

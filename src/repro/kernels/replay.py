@@ -29,6 +29,15 @@ tail and the validity prefix are applied by the wrapper outside the kernel
 ``ref.replay_delta_ref``, this kernel, and the numpy twin
 ``fragcache.replay`` share the exact same tail semantics.
 
+Layout: the compare is laid out *transposed* — seed rows on the sublanes,
+output rows on the lanes.  ``src`` streams as a lane-dense ``(1, J_TILE)``
+row, the seed tile keeps its natural ``(I_TILE, V)`` shape (a column
+slice ``seed[:, c]`` is then a sublane vector that broadcasts across the
+lanes for free), and the kernel writes a ``(V, J_TILE)`` output block that
+the wrapper transposes back.  Each column is reduced separately over the
+sublanes, so no 3-D (and no i1 3-D) intermediate exists: Mosaic cannot
+reshape an i1 vector to 3-D.
+
 Grid: (num_out_tiles, num_seed_tiles); TPU grids iterate the last axis
 fastest and sequentially, so partial gathers accumulate in the output block
 across seed-tile steps (init at i == 0).
@@ -39,8 +48,11 @@ from __future__ import annotations
 import functools
 
 import jax
+import numpy as np
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.layout import ZERO, as_row
 
 DEFAULT_J_TILE = 256  # output (delta) rows per tile
 DEFAULT_I_TILE = 512  # seed rows streamed per tile
@@ -48,17 +60,17 @@ DEFAULT_I_TILE = 512  # seed rows streamed per tile
 
 def _replay_kernel(src_ref, seed_ref, out_ref):
     i = pl.program_id(1)
-    src = src_ref[...]  # [J_TILE] int32
+    src = src_ref[...]  # [1, J_TILE] int32
     seed = seed_ref[...]  # [I_TILE, V] int32
-    j_tile = src.shape[0]
-    i_tile = seed.shape[0]
-    # global seed row index per (out row, tile element): [J_TILE, I_TILE]
-    i_abs = (i * i_tile
-             + jax.lax.broadcasted_iota(jnp.int32, (j_tile, i_tile), 1))
-    hit = src[:, None] == i_abs
-    partial = jnp.sum(
-        jnp.where(hit[:, :, None], seed[None, :, :], jnp.int32(0)), axis=1,
-        dtype=jnp.int32)  # int32 accumulation: x64 mode must not promote
+    i_tile, n_vars = seed.shape
+    # global seed row index per (tile element, out row): [I_TILE, J_TILE]
+    i_abs = i * i_tile + jax.lax.broadcasted_iota(
+        jnp.int32, (i_tile, 1), 0)
+    hit = i_abs == src
+    partial = jnp.concatenate(
+        [jnp.sum(jnp.where(hit, seed[:, c:c + 1], np.int32(0)), axis=0,
+                 keepdims=True, dtype=jnp.int32)  # int32: x64 must not widen
+         for c in range(n_vars)], axis=0)  # [V, J_TILE]
 
     @pl.when(i == 0)
     def _init():
@@ -92,22 +104,21 @@ def replay_delta_pallas(seed_rows: jnp.ndarray, src: jnp.ndarray,
     live = jnp.arange(m, dtype=jnp.int32) < n_out
     # padding/dead src entries match no seed row inside the kernel
     src_k = jnp.where(live, src.astype(jnp.int32), jnp.int32(-1))
-    j_pad = -m % j_tile
-    i_pad = -cap % i_tile
-    src_p = jnp.pad(src_k, (0, j_pad), constant_values=-1)
-    seed_p = jnp.pad(seed_rows, ((0, i_pad), (0, 0)))
-    grid = (src_p.shape[0] // j_tile, seed_p.shape[0] // i_tile)
-    out = pl.pallas_call(
+    src_p = as_row(src_k, j_tile, -1)
+    seed_p = jnp.pad(seed_rows, ((0, -cap % i_tile), (0, 0)))
+    grid = (src_p.shape[1] // j_tile, seed_p.shape[0] // i_tile)
+    out_t = pl.pallas_call(
         _replay_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((j_tile,), lambda j, i: (j,)),
-            pl.BlockSpec((i_tile, n_vars), lambda j, i: (i, 0)),
+            pl.BlockSpec((1, j_tile), lambda j, i: (ZERO, j)),
+            pl.BlockSpec((i_tile, n_vars), lambda j, i: (i, ZERO)),
         ],
-        out_specs=pl.BlockSpec((j_tile, n_vars), lambda j, i: (j, 0)),
-        out_shape=jax.ShapeDtypeStruct((src_p.shape[0], n_vars), jnp.int32),
+        out_specs=pl.BlockSpec((n_vars, j_tile), lambda j, i: (ZERO, j)),
+        out_shape=jax.ShapeDtypeStruct((n_vars, src_p.shape[1]), jnp.int32),
         interpret=interpret,
-    )(src_p, seed_p)[:m]
+    )(src_p, seed_p)
+    out = out_t.T[:m]
     # shared tail (identical to the oracle): write-col overlay, dead-row
     # UNBOUND fill, prefix validity
     for w, c in enumerate(write_cols):

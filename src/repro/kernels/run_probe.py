@@ -22,6 +22,12 @@ in one fused pass with a coalesced HBM->VMEM stream and zero gathers.  The
 window mask makes value padding a non-issue: padded positions sit at
 ``k_abs >= n >= hi_r`` and padded rows get the empty run ``[0, 0)``.
 
+Layout (``kernels.layout``): values stream as a lane-dense ``(1, V_TILE)``
+row; ``lo``/``hi``/``targets`` and both outputs are ``(R_TILE, 1)``
+columns.  Membership is an int32 0/1 max-reduction and the kernel emits
+it as int32 (the wrapper converts): Mosaic has no bool outputs, and under
+``jax_enable_x64`` ``jnp.any`` lowers to a float64 reduction it refuses.
+
 Grid: (num_r_tiles, num_v_tiles); TPU grids iterate the last axis fastest
 and sequentially, so partial ranks accumulate in the output block across
 value-tile steps (init at j == 0).  ``broadcasted_iota`` is 2D — TPU
@@ -54,29 +60,36 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.layout import ZERO, as_col, as_row
+
 
 DEFAULT_R_TILE = 256
 DEFAULT_V_TILE = 2048
 
 
+def _window_partials(values, lo, hi, targets, tile):
+    """One value tile's ``(sum(lt), max(eq))`` over each row's run window.
+
+    ``values`` is the ``[1, V_TILE]`` tile whose first element sits at
+    absolute index ``tile * V_TILE``; ``lo``/``hi``/``targets`` are
+    ``[R_TILE, 1]`` columns.  Both partials are int32 ``[R_TILE, 1]``.
+    """
+    v_tile = values.shape[1]
+    k_abs = (tile * v_tile
+             + jax.lax.broadcasted_iota(jnp.int32, (1, v_tile), 1))
+    in_run = (k_abs >= lo) & (k_abs < hi)  # [R_TILE, V_TILE]
+    lt = (in_run & (values < targets)).astype(jnp.int32)
+    eq = (in_run & (values == targets)).astype(jnp.int32)
+    return (jnp.sum(lt, axis=1, keepdims=True, dtype=jnp.int32),
+            jnp.max(eq, axis=1, keepdims=True))
+
+
 def _run_probe_kernel(values_ref, lo_ref, hi_ref, targets_ref,
                       pos_ref, contains_ref):
     j = pl.program_id(1)
-    values = values_ref[...]  # [V_TILE]
-    lo = lo_ref[...]  # [R_TILE] int32
-    hi = hi_ref[...]  # [R_TILE] int32
-    targets = targets_ref[...]  # [R_TILE]
-    r_tile = lo.shape[0]
-    v_tile = values.shape[0]
-
-    # absolute value index per (row, tile element): [R_TILE, V_TILE]
-    k_abs = (j * v_tile
-             + jax.lax.broadcasted_iota(jnp.int32, (r_tile, v_tile), 1))
-    in_run = (k_abs >= lo[:, None]) & (k_abs < hi[:, None])
-    lt = in_run & (values[None, :] < targets[:, None])
-    eq = in_run & (values[None, :] == targets[:, None])
-    partial_pos = jnp.sum(lt, axis=1, dtype=jnp.int32)
-    partial_contains = jnp.any(eq, axis=1)
+    lo = lo_ref[...]
+    partial_pos, partial_contains = _window_partials(
+        values_ref[...], lo, hi_ref[...], targets_ref[...], j)
 
     @pl.when(j == 0)
     def _init():
@@ -86,7 +99,24 @@ def _run_probe_kernel(values_ref, lo_ref, hi_ref, targets_ref,
     @pl.when(j != 0)
     def _accum():
         pos_ref[...] = pos_ref[...] + partial_pos
-        contains_ref[...] = contains_ref[...] | partial_contains
+        contains_ref[...] = jnp.maximum(contains_ref[...], partial_contains)
+
+
+def _operands(values, lo, hi, targets, r_tile, v_tile):
+    """Promote, pad and lay out the run_probe operands (shared by both
+    variants).  Value padding uses the +max of the *promoted* dtype —
+    promotion must happen before padding, or an int32 column probed by
+    int64 targets would pad with int32-max values that promoted targets
+    can exceed — and row padding the empty run ``[0, 0)``."""
+    dt = jnp.promote_types(values.dtype, targets.dtype)
+    maxval = jnp.iinfo(dt).max
+    if values.shape[0] == 0:  # keep one (all-padding) tile in the grid
+        values = jnp.full((1,), maxval, dt)
+    values_p = as_row(values.astype(dt), v_tile, maxval)
+    lo_p = as_col(lo.astype(jnp.int32), r_tile, 0)
+    hi_p = as_col(hi.astype(jnp.int32), r_tile, 0)
+    targets_p = as_col(targets.astype(dt), r_tile, 0)
+    return values_p, lo_p, hi_p, targets_p
 
 
 @functools.partial(jax.jit, static_argnames=("r_tile", "v_tile", "interpret"))
@@ -106,56 +136,31 @@ def run_probe_pallas(values: jnp.ndarray, lo: jnp.ndarray, hi: jnp.ndarray,
     Each run ``values[lo_i:hi_i)`` must be individually sorted ascending
     (the PSO/POS store layout guarantees this).  Empty runs
     (``lo[i] == hi[i]``) yield ``pos == lo`` and ``contains == False``.
-    Value padding uses the +max of the *promoted* dtype — promotion must
-    happen before padding, or an int32 column probed by int64 targets
-    would pad with int32-max values that promoted targets can exceed —
-    and row padding the empty run ``[0, 0)``; the in-run window mask
-    keeps both inert.
+    Padding (see ``_operands``) is kept inert by the in-run window mask.
     """
-    n = values.shape[0]
     r = lo.shape[0]
-    dt = jnp.promote_types(values.dtype, targets.dtype)
-    maxval = jnp.iinfo(dt).max
-    n_pad = -n % v_tile if n else v_tile
-    r_pad = -r % r_tile
-    values_p = jnp.pad(values.astype(dt), (0, n_pad), constant_values=maxval)
-    lo_p = jnp.pad(lo.astype(jnp.int32), (0, r_pad))
-    hi_p = jnp.pad(hi.astype(jnp.int32), (0, r_pad))
-    targets_p = jnp.pad(targets.astype(dt), (0, r_pad))
-
-    grid = (lo_p.shape[0] // r_tile, values_p.shape[0] // v_tile)
+    values_p, lo_p, hi_p, targets_p = _operands(values, lo, hi, targets,
+                                                r_tile, v_tile)
+    r_len = lo_p.shape[0]
+    grid = (r_len // r_tile, values_p.shape[1] // v_tile)
+    col = pl.BlockSpec((r_tile, 1), lambda i, j: (i, ZERO))
     pos, contains = pl.pallas_call(
         _run_probe_kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((v_tile,), lambda i, j: (j,)),
-            pl.BlockSpec((r_tile,), lambda i, j: (i,)),
-            pl.BlockSpec((r_tile,), lambda i, j: (i,)),
-            pl.BlockSpec((r_tile,), lambda i, j: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((r_tile,), lambda i, j: (i,)),
-            pl.BlockSpec((r_tile,), lambda i, j: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((lo_p.shape[0],), jnp.int32),
-            jax.ShapeDtypeStruct((lo_p.shape[0],), jnp.bool_),
-        ],
+        in_specs=[pl.BlockSpec((1, v_tile), lambda i, j: (ZERO, j)),
+                  col, col, col],
+        out_specs=[col, col],
+        out_shape=[jax.ShapeDtypeStruct((r_len, 1), jnp.int32)] * 2,
         interpret=interpret,
     )(values_p, lo_p, hi_p, targets_p)
-    return pos[:r], contains[:r]
+    return pos[:r, 0], contains[:r, 0] != 0
 
 
 def _run_probe_prefetch_kernel(base_ref, nwin_ref, values_ref, lo_ref,
                                hi_ref, targets_ref, pos_ref, contains_ref):
     i = pl.program_id(0)
     j = pl.program_id(1)
-    lo = lo_ref[...]  # [R_TILE] int32
-    hi = hi_ref[...]  # [R_TILE] int32
-    targets = targets_ref[...]  # [R_TILE]
-    values = values_ref[...]  # [V_TILE] — the window's (base+min(j,nwin-1))th
-    r_tile = lo.shape[0]
-    v_tile = values.shape[0]
+    lo = lo_ref[...]
     nwin = nwin_ref[i]
     # the value tile actually resident: the index map clamps steps past the
     # window onto its last tile (whose copy Pallas then skips) — recompute
@@ -165,17 +170,14 @@ def _run_probe_prefetch_kernel(base_ref, nwin_ref, values_ref, lo_ref,
     @pl.when(j == 0)
     def _init():
         pos_ref[...] = lo
-        contains_ref[...] = jnp.zeros((r_tile,), jnp.bool_)
+        contains_ref[...] = jnp.zeros_like(lo)
 
     @pl.when(j < nwin)
     def _accum():
-        k_abs = (t * v_tile
-                 + jax.lax.broadcasted_iota(jnp.int32, (r_tile, v_tile), 1))
-        in_run = (k_abs >= lo[:, None]) & (k_abs < hi[:, None])
-        lt = in_run & (values[None, :] < targets[:, None])
-        eq = in_run & (values[None, :] == targets[:, None])
-        pos_ref[...] = pos_ref[...] + jnp.sum(lt, axis=1, dtype=jnp.int32)
-        contains_ref[...] = contains_ref[...] | jnp.any(eq, axis=1)
+        partial_pos, partial_contains = _window_partials(
+            values_ref[...], lo, hi_ref[...], targets_ref[...], t)
+        pos_ref[...] = pos_ref[...] + partial_pos
+        contains_ref[...] = jnp.maximum(contains_ref[...], partial_contains)
 
 
 @functools.partial(jax.jit, static_argnames=("r_tile", "v_tile", "interpret"))
@@ -197,56 +199,39 @@ def run_probe_prefetch_pallas(values: jnp.ndarray, lo: jnp.ndarray,
     the touched windows are — the engine's common case, where a wave's
     runs cluster in a sliver of the column.
     """
-    n = values.shape[0]
     r = lo.shape[0]
-    dt = jnp.promote_types(values.dtype, targets.dtype)
-    maxval = jnp.iinfo(dt).max
-    n_pad = -n % v_tile if n else v_tile
-    r_pad = -r % r_tile
-    values_p = jnp.pad(values.astype(dt), (0, n_pad), constant_values=maxval)
-    lo_p = jnp.pad(lo.astype(jnp.int32), (0, r_pad))
-    hi_p = jnp.pad(hi.astype(jnp.int32), (0, r_pad))
-    targets_p = jnp.pad(targets.astype(dt), (0, r_pad))
-
+    values_p, lo_p, hi_p, targets_p = _operands(values, lo, hi, targets,
+                                                r_tile, v_tile)
     n_r_tiles = lo_p.shape[0] // r_tile
-    n_v_tiles = values_p.shape[0] // v_tile
+    n_v_tiles = values_p.shape[1] // v_tile
 
     # per row-block window of touched value tiles, over NON-empty runs
     # only: empty runs (hi <= lo — row padding, filtered rows, non-owned
     # rows under sharding) contribute nothing, so an all-empty block gets
     # nwin == 0 and streams zero value tiles
-    nonempty = hi_p > lo_p
-    lo_t = jnp.where(nonempty, lo_p // v_tile, jnp.int32(n_v_tiles))
-    hi_t = jnp.where(nonempty, (hi_p - 1) // v_tile, jnp.int32(-1))
+    lo_f, hi_f = lo_p[:, 0], hi_p[:, 0]
+    nonempty = hi_f > lo_f
+    lo_t = jnp.where(nonempty, lo_f // v_tile, jnp.int32(n_v_tiles))
+    hi_t = jnp.where(nonempty, (hi_f - 1) // v_tile, jnp.int32(-1))
     blk_lo = jnp.min(lo_t.reshape(n_r_tiles, r_tile), axis=1)
     blk_hi = jnp.max(hi_t.reshape(n_r_tiles, r_tile), axis=1)
     base = jnp.where(blk_hi >= blk_lo, blk_lo, 0).astype(jnp.int32)
     nwin = jnp.maximum(blk_hi - blk_lo + 1, 0).astype(jnp.int32)
 
     def value_map(i, j, base, nwin):
-        return (base[i] + jnp.minimum(j, jnp.maximum(nwin[i] - 1, 0)),)
+        return (ZERO, base[i] + jnp.minimum(j, jnp.maximum(nwin[i] - 1, 0)))
 
+    col = pl.BlockSpec((r_tile, 1), lambda i, j, b, w: (i, ZERO))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n_r_tiles, n_v_tiles),
-        in_specs=[
-            pl.BlockSpec((v_tile,), value_map),
-            pl.BlockSpec((r_tile,), lambda i, j, b, w: (i,)),
-            pl.BlockSpec((r_tile,), lambda i, j, b, w: (i,)),
-            pl.BlockSpec((r_tile,), lambda i, j, b, w: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((r_tile,), lambda i, j, b, w: (i,)),
-            pl.BlockSpec((r_tile,), lambda i, j, b, w: (i,)),
-        ],
+        in_specs=[pl.BlockSpec((1, v_tile), value_map), col, col, col],
+        out_specs=[col, col],
     )
     pos, contains = pl.pallas_call(
         _run_probe_prefetch_kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((lo_p.shape[0],), jnp.int32),
-            jax.ShapeDtypeStruct((lo_p.shape[0],), jnp.bool_),
-        ],
+        out_shape=[jax.ShapeDtypeStruct(lo_p.shape, jnp.int32)] * 2,
         interpret=interpret,
     )(base, nwin, values_p, lo_p, hi_p, targets_p)
-    return pos[:r], contains[:r]
+    return pos[:r, 0], contains[:r, 0] != 0

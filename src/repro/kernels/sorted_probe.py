@@ -12,7 +12,7 @@ stream the run through VMEM in tiles and compute, for every query key,
 
     rank_lo(q)  = sum_tiles  sum(tile_keys <  q)    (= searchsorted left)
     rank_hi(q)  = sum_tiles  sum(tile_keys <= q)    (= searchsorted right)
-    contains(q) = or_tiles   any(tile_keys == q)
+    contains(q) = rank_lo(q) < rank_hi(q)
 
 i.e. probe-by-broadcast-compare-reduce: a dense [Q_tile x K_tile] compare on
 the VPU per grid step.  For run lengths up to ~10^6 this linear-scan-in-
@@ -25,6 +25,14 @@ Emitting both rank sides from one kernel pass is what lets the engine's
 ``eqrange`` (equal-range lookup, the per-branch run locator) lower to a
 single fused probe instead of two searchsorted calls — see
 ``repro.kernels.ops.eqrange``.
+
+Layout (``kernels.layout``): keys stream as a lane-dense ``(1, K_TILE)``
+row and queries sit in a ``(Q_TILE, 1)`` column, so the compare is a plain
+2-D broadcast and each lane reduction lands in the query column.  Keys and
+queries reach the kernel as int32 on the chip (Mosaic has no 64-bit
+vectors; ``rdf.store`` keeps its composite keys in int32), and every
+reduction names its int32 dtype — under ``jax_enable_x64`` an unnamed
+``jnp.sum`` would widen to int64.
 
 Grid: (num_q_tiles, num_k_tiles); TPU grids iterate the last axis fastest
 and sequentially, so the kernel accumulates partial ranks in the output
@@ -39,36 +47,33 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.layout import ZERO, as_col, as_row
+
 
 DEFAULT_Q_TILE = 256
 DEFAULT_K_TILE = 2048
 
 
-def _probe_kernel(keys_ref, queries_ref, rank_lo_ref, rank_hi_ref,
-                  contains_ref):
+def _probe_kernel(keys_ref, queries_ref, rank_lo_ref, rank_hi_ref):
     j = pl.program_id(1)
-    keys = keys_ref[...]  # [K_TILE]
-    qs = queries_ref[...]  # [Q_TILE]
+    keys = keys_ref[...]  # [1, K_TILE]
+    qs = queries_ref[...]  # [Q_TILE, 1]
 
-    # dense compare: [Q_TILE, K_TILE] on the VPU
-    lt = keys[None, :] < qs[:, None]
-    le = keys[None, :] <= qs[:, None]
-    eq = keys[None, :] == qs[:, None]
-    partial_lo = jnp.sum(lt, axis=1, dtype=jnp.int32)
-    partial_hi = jnp.sum(le, axis=1, dtype=jnp.int32)
-    partial_contains = jnp.any(eq, axis=1)
+    # dense compare: [Q_TILE, K_TILE] on the VPU, reduced over the lanes
+    partial_lo = jnp.sum((keys < qs).astype(jnp.int32), axis=1,
+                         keepdims=True, dtype=jnp.int32)
+    partial_hi = jnp.sum((keys <= qs).astype(jnp.int32), axis=1,
+                         keepdims=True, dtype=jnp.int32)
 
     @pl.when(j == 0)
     def _init():
         rank_lo_ref[...] = partial_lo
         rank_hi_ref[...] = partial_hi
-        contains_ref[...] = partial_contains
 
     @pl.when(j != 0)
     def _accum():
         rank_lo_ref[...] = rank_lo_ref[...] + partial_lo
         rank_hi_ref[...] = rank_hi_ref[...] + partial_hi
-        contains_ref[...] = contains_ref[...] | partial_contains
 
 
 @functools.partial(jax.jit, static_argnames=("q_tile", "k_tile", "interpret"))
@@ -88,42 +93,29 @@ def sorted_probe_pallas(keys: jnp.ndarray, queries: jnp.ndarray,
     ``keys`` must be sorted ascending.  Both arrays are padded to tile
     multiples; key padding uses +max, which is invisible to any query
     below the dtype max.  A query *equal* to the dtype max would see the
-    padding in the ``<=``/``==`` compares, so the wrapper corrects for it:
-    ``rank_hi`` is clamped to ``n`` (the true right-rank at the max query
-    is always ``n``) and ``contains`` is derived as ``rank_lo < rank_hi``
-    — exact for every query value, keeping byte-parity with the jnp
-    oracle unconditional.
+    padding in the ``<=`` compare, so the wrapper clamps ``rank_hi`` to
+    ``n`` (the true right-rank at the max query is always ``n``) and
+    derives ``contains`` as ``rank_lo < rank_hi`` — exact for every query
+    value, keeping byte-parity with the jnp oracle unconditional.
     """
     n = keys.shape[0]
     q = queries.shape[0]
-    dt = keys.dtype
+    dt = jnp.promote_types(keys.dtype, queries.dtype)
     maxval = jnp.iinfo(dt).max
-    n_pad = -n % k_tile
-    q_pad = -q % q_tile
-    keys_p = jnp.pad(keys, (0, n_pad), constant_values=maxval)
-    queries_p = jnp.pad(queries, (0, q_pad), constant_values=maxval)
+    keys_p = as_row(keys.astype(dt), k_tile, maxval)
+    queries_p = as_col(queries.astype(dt), q_tile, maxval)
+    q_len = queries_p.shape[0]
 
-    grid = (queries_p.shape[0] // q_tile, keys_p.shape[0] // k_tile)
-    rank_lo, rank_hi, contains = pl.pallas_call(
+    grid = (q_len // q_tile, keys_p.shape[1] // k_tile)
+    col = pl.BlockSpec((q_tile, 1), lambda i, j: (i, ZERO))
+    rank_lo, rank_hi = pl.pallas_call(
         _probe_kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((k_tile,), lambda i, j: (j,)),
-            pl.BlockSpec((q_tile,), lambda i, j: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((q_tile,), lambda i, j: (i,)),
-            pl.BlockSpec((q_tile,), lambda i, j: (i,)),
-            pl.BlockSpec((q_tile,), lambda i, j: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((queries_p.shape[0],), jnp.int32),
-            jax.ShapeDtypeStruct((queries_p.shape[0],), jnp.int32),
-            jax.ShapeDtypeStruct((queries_p.shape[0],), jnp.bool_),
-        ],
+        in_specs=[pl.BlockSpec((1, k_tile), lambda i, j: (ZERO, j)), col],
+        out_specs=[col, col],
+        out_shape=[jax.ShapeDtypeStruct((q_len, 1), jnp.int32)] * 2,
         interpret=interpret,
     )(keys_p, queries_p)
-    rank_lo, rank_hi, contains = rank_lo[:q], rank_hi[:q], contains[:q]
-    rank_hi = jnp.minimum(rank_hi, n)
-    contains = contains & (rank_lo < rank_hi)
-    return rank_lo, rank_hi, contains
+    rank_lo = rank_lo[:q, 0]
+    rank_hi = jnp.minimum(rank_hi[:q, 0], n)
+    return rank_lo, rank_hi, rank_lo < rank_hi

@@ -15,8 +15,11 @@ server's access paths need exactly these two):
 - **POS order** — sorted by ``(p, o, s)``.  ``(?s, p, o)`` is a binary-search
   run whose subjects are sorted — again merge-intersectable.
 
-Composite int64 keys (``p*R + s`` etc.) make every lookup a vectorised
-``searchsorted``; radix overflow is checked at build time.
+Composite keys (``p*R + s`` etc.) make every lookup a vectorised
+``searchsorted``.  They are **int32**: the TPU's vector units have no
+64-bit integers, so the probe kernels take int32 keys.  ``build`` checks
+the radix product against the int32 bound and refuses a graph past it
+(WatDiv at ~10M triples needs ~5e7 of the ~2.1e9 available).
 
 The store keeps **numpy** copies for host-side query planning (join ordering
 uses exact run lengths — the Def. 6 cardinality metadata with eps = 0) and
@@ -63,8 +66,11 @@ from typing import NamedTuple
 import jax.numpy as jnp
 import numpy as np
 
-_INT64_MAX = np.int64(np.iinfo(np.int64).max)
 _INT32_MAX = np.int32(np.iinfo(np.int32).max)
+# every composite key and probe bound is < (n_predicates + 1) * n_terms
+# (one predicate id past the last is the shard padding predicate), and
+# int32 max is the key padding — so the product must stay below it
+KEY_LIMIT = 2**31 - 1
 _EPOCH_LOG_MAX = 64
 
 
@@ -85,19 +91,19 @@ class StoreArrays(NamedTuple):
     """
 
     # PSO order (base)
-    key_ps_pso: jnp.ndarray  # int64[n]  p*R_term + s, ascending
+    key_ps_pso: jnp.ndarray  # int32[n]  p*R_term + s, ascending
     s_pso: jnp.ndarray  # int32[n]
     o_pso: jnp.ndarray  # int32[n]
     # POS order (base)
-    key_po_pos: jnp.ndarray  # int64[n]  p*R_term + o, ascending
+    key_po_pos: jnp.ndarray  # int32[n]  p*R_term + o, ascending
     s_pos: jnp.ndarray  # int32[n]
     o_pos: jnp.ndarray  # int32[n]  (object of each POS row; run-constant)
     # delta: inserts, PSO order
-    ins_key_ps: jnp.ndarray  # int64[m]
+    ins_key_ps: jnp.ndarray  # int32[m]
     ins_s_pso: jnp.ndarray  # int32[m]
     ins_o_pso: jnp.ndarray  # int32[m]
     # delta: inserts, POS order
-    ins_key_po: jnp.ndarray  # int64[m]
+    ins_key_po: jnp.ndarray  # int32[m]
     ins_s_pos: jnp.ndarray  # int32[m]
     ins_o_pos: jnp.ndarray  # int32[m]
     # delta: tombstones (sorted base positions + pos-rank columns)
@@ -194,21 +200,19 @@ class TripleStore:
             n_predicates = int(p.max(initial=0)) + 1
         n = s.shape[0]
         r = np.int64(n_terms)
-        # radix-overflow check: key = p * R + term must fit int64.
-        if (n_predicates + 1) * int(r) >= 2**62:
-            raise ValueError("composite key radix overflow; shard the dictionary")
+        check_key_radix(n_terms, n_predicates)
 
         # deduplicate (RDF graphs are triple *sets*)
         pso = np.stack([p, s, o], axis=1)
         pso = np.unique(pso, axis=0)  # sorts lexicographically by (p, s, o)
         p_, s_, o_ = pso[:, 0], pso[:, 1], pso[:, 2]
         n = p_.shape[0]
-        key_ps = p_ * r + s_
+        key_ps = (p_ * r + s_).astype(np.int32)
 
         order_pos = np.lexsort((s_, o_, p_))  # sort by (p, o, s)
         s_pos = s_[order_pos]
         o_pos = o_[order_pos]
-        key_po = p_[order_pos] * r + o_pos
+        key_po = (p_[order_pos] * r + o_pos).astype(np.int32)
 
         # CSR over predicates (same boundaries in both orders).
         pred_offsets = np.searchsorted(p_, np.arange(n_predicates + 2))
@@ -275,7 +279,7 @@ class TripleStore:
         """The 10 host delta arrays padded to ``(m_pad, t_pad)`` lengths.
 
         Padding values keep every consumer exact: insert keys pad with
-        int64 max (outside any real eqrange), insert value columns with 0
+        int32 max (outside any real eqrange), insert value columns with 0
         (never gathered — runs exclude padding), tombstone positions with
         ``n_base`` (no real base position reaches it, and the counts use
         strict ``<``), and the adj column with int32 max (a live rank
@@ -287,10 +291,10 @@ class TripleStore:
             return np.concatenate([a, np.full(n - a.shape[0], val, a.dtype)])
 
         return (
-            pad(self.h_ins_key_ps, m_pad, _INT64_MAX),
+            pad(self.h_ins_key_ps, m_pad, _INT32_MAX),
             pad(self.h_ins_s_pso, m_pad, 0),
             pad(self.h_ins_o_pso, m_pad, 0),
-            pad(self.h_ins_key_po, m_pad, _INT64_MAX),
+            pad(self.h_ins_key_po, m_pad, _INT32_MAX),
             pad(self.h_ins_s_pos, m_pad, 0),
             pad(self.h_ins_o_pos, m_pad, 0),
             pad(self.h_tomb_pos_ps, t_pad, np.int32(self.n_base)),
@@ -360,9 +364,18 @@ class TripleStore:
         return frozenset(acc)
 
     # ------------------------------------------------------------- write path
+    def key(self, p: int, term: int) -> np.int32:
+        """Composite key ``p * n_terms + term`` as an int32 scalar.
+
+        Host lookups must probe the int32 key columns with an int32
+        scalar: numpy widens the whole column to the probe's dtype on
+        every mixed-dtype ``searchsorted``.
+        """
+        return np.int32(int(p) * self.n_terms + int(term))
+
     def _base_pos_ps(self, p: int, s: int, o: int) -> int:
         """PSO position of a base triple, or -1 if absent from the base."""
-        key = np.int64(p) * self.n_terms + s
+        key = self.key(p, s)
         lo = int(np.searchsorted(self.h_key_ps, key, side="left"))
         hi = int(np.searchsorted(self.h_key_ps, key, side="right"))
         j = int(np.searchsorted(self.h_o_pso[lo:hi], o, side="left"))
@@ -372,7 +385,7 @@ class TripleStore:
 
     def _base_pos_po(self, p: int, s: int, o: int) -> int:
         """POS position of a base triple (caller guarantees presence)."""
-        key = np.int64(p) * self.n_terms + o
+        key = self.key(p, o)
         lo = int(np.searchsorted(self.h_key_po, key, side="left"))
         hi = int(np.searchsorted(self.h_key_po, key, side="right"))
         j = int(np.searchsorted(self.h_s_pos[lo:hi], s, side="left"))
@@ -445,11 +458,13 @@ class TripleStore:
         r = np.int64(self.n_terms)
         ins = np.array(sorted(self._ins_set), np.int64).reshape(-1, 3)
         p_, s_, o_ = ins[:, 0], ins[:, 1], ins[:, 2]
-        self.h_ins_key_ps = p_ * r + s_  # (p, s, o) sort == PSO layout
+        # (p, s, o) sort == PSO layout
+        self.h_ins_key_ps = (p_ * r + s_).astype(np.int32)
         self.h_ins_s_pso = s_.astype(np.int32)
         self.h_ins_o_pso = o_.astype(np.int32)
         order_pos = np.lexsort((s_, o_, p_))
-        self.h_ins_key_po = p_[order_pos] * r + o_[order_pos]
+        self.h_ins_key_po = (p_[order_pos] * r
+                             + o_[order_pos]).astype(np.int32)
         self.h_ins_s_pos = s_[order_pos].astype(np.int32)
         self.h_ins_o_pos = o_[order_pos].astype(np.int32)
         # tombstones sorted by (p, s, o) enumerate base PSO positions in
@@ -522,14 +537,14 @@ class TripleStore:
 
     def ps_run(self, p: int, s: int) -> tuple[int, int]:
         """Run [lo, hi) of (p, s, ?o) *base* rows in PSO order."""
-        key = np.int64(p) * self.n_terms + s
+        key = self.key(p, s)
         lo = int(np.searchsorted(self.h_key_ps, key, side="left"))
         hi = int(np.searchsorted(self.h_key_ps, key, side="right"))
         return lo, hi
 
     def po_run(self, p: int, o: int) -> tuple[int, int]:
         """Run [lo, hi) of (?s, p, o) *base* rows in POS order."""
-        key = np.int64(p) * self.n_terms + o
+        key = self.key(p, o)
         lo = int(np.searchsorted(self.h_key_po, key, side="left"))
         hi = int(np.searchsorted(self.h_key_po, key, side="right"))
         return lo, hi
@@ -560,20 +575,19 @@ class TripleStore:
             return base - (t in self._tomb_set) + (t in self._ins_set)
         if s is not None:
             lo, hi = self.ps_run(p, s)
-            key = np.int64(p) * self.n_terms + s
             return (hi - lo) - self._tombs_in(self.h_tomb_pos_ps, lo, hi) \
-                + self._ins_count_ps(key, key + 1)
+                + self._ins_count_ps(self.key(p, s), self.key(p, s + 1))
         if o is not None:
             lo, hi = self.po_run(p, o)
-            key = np.int64(p) * self.n_terms + o
-            ins = int(np.searchsorted(self.h_ins_key_po, key + 1, "left")
-                      - np.searchsorted(self.h_ins_key_po, key, "left"))
+            ins = int(np.searchsorted(self.h_ins_key_po, self.key(p, o + 1),
+                                      "left")
+                      - np.searchsorted(self.h_ins_key_po, self.key(p, o),
+                                        "left"))
             return (hi - lo) - self._tombs_in(self.h_tomb_pos_po, lo, hi) \
                 + ins
         lo, hi = self.pred_run(p)
-        key = np.int64(p) * self.n_terms
         return (hi - lo) - self._tombs_in(self.h_tomb_pos_ps, lo, hi) \
-            + self._ins_count_ps(key, key + np.int64(self.n_terms))
+            + self._ins_count_ps(self.key(p, 0), self.key(p + 1, 0))
 
     def max_ins_degrees(self) -> tuple[np.ndarray, np.ndarray]:
         """Per-predicate max insert-run lengths, (p,s)-keyed and
@@ -666,6 +680,23 @@ class TripleStore:
         delta = [jnp.stack([jnp.asarray(d[i]) for d in deltas])
                  for i in range(10)]
         return StoreArrays(*base, *delta)
+
+
+def check_key_radix(n_terms: int, n_predicates: int) -> None:
+    """Refuse a dictionary whose composite keys would not fit int32.
+
+    Keys are ``p * n_terms + term`` for predicate ids up to
+    ``n_predicates`` (the shard padding predicate), so the largest key or
+    probe bound is below ``(n_predicates + 1) * n_terms``; it must stay
+    below ``KEY_LIMIT``, which is also the key padding value.
+    """
+    bound = (int(n_predicates) + 1) * int(n_terms)
+    if bound >= KEY_LIMIT:
+        raise ValueError(
+            f"composite key radix overflow: (n_predicates + 1) * n_terms = "
+            f"({n_predicates} + 1) * {n_terms} = {bound} does not fit the "
+            f"int32 keys the probe kernels take (limit {KEY_LIMIT}); "
+            f"shard the dictionary")
 
 
 def _owner(s: int, n_shards: int) -> int:

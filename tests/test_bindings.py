@@ -11,7 +11,9 @@ import numpy as np
 
 from _hyp import given, settings, st
 
-from repro.core.bindings import expand
+import pytest
+
+from repro.core.bindings import BindingTable, compact, expand
 from repro.kernels.ops import eqrange, run_contains, searchsorted_in_runs
 
 
@@ -168,3 +170,23 @@ def test_expand_enumerates_runs(data):
            for i in range(min(cap, total))]
     assert got == want[:cap]
     assert np.asarray(ex.valid).sum() == min(cap, total)
+
+
+@pytest.mark.parametrize("pattern", ["all", "none", "prefix", "suffix",
+                                     "random"])
+def test_compact_is_a_stable_partition(pattern):
+    """``compact`` moves valid rows to a prefix in their original order,
+    then the invalid rows in theirs — the permutation of a stable argsort
+    on ``~valid``, built without a sort."""
+    rng = np.random.default_rng(7)
+    n = 37
+    valid = {"all": np.ones(n, bool), "none": np.zeros(n, bool),
+             "prefix": np.arange(n) < 11, "suffix": np.arange(n) >= 11,
+             "random": rng.random(n) < 0.4}[pattern]
+    rows = rng.integers(-1, 1000, (n, 3)).astype(np.int32)
+    out = compact(BindingTable(jnp.asarray(rows), jnp.asarray(valid),
+                               jnp.asarray(False)))
+    order = np.argsort(~valid, kind="stable")
+    np.testing.assert_array_equal(np.asarray(out.rows), rows[order])
+    np.testing.assert_array_equal(np.asarray(out.valid), valid[order])
+

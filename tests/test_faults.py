@@ -431,6 +431,36 @@ def test_kernel_breaker_opens_serves_oracle_and_recovers():
         br.reset()
 
 
+def test_kernel_fault_is_logged_once_per_primitive(caplog):
+    """A Pallas fault is never silent: the first one of a primitive is
+    logged with its exception before the oracle serves the call; repeats
+    of the same primitive do not flood the log."""
+    br = kops.BREAKER
+    br.reset()
+    old_force = kops.FORCE
+    kops.FORCE = "pallas"
+    kops._LOGGED_FAULTS.discard("sorted_probe")
+    try:
+        keys = jnp.asarray(np.arange(0, 128, 2), jnp.int32)
+        qs = jnp.asarray([0, 7, 50, 98], jnp.int32)
+        plan = faults.FaultPlan(0, {
+            "kernel": faults.FaultSpec("raise",
+                                       when={"prim": "sorted_probe"},
+                                       times=2),
+        })
+        with caplog.at_level("WARNING", logger=kops.__name__), \
+                faults.injecting(plan):
+            for _ in range(2):
+                got = kops.sorted_probe(keys, qs)
+        assert np.array_equal(np.asarray(got[0]),
+                              np.asarray(ref.sorted_probe_ref(keys, qs)[0]))
+        logged = [r for r in caplog.records if "sorted_probe" in r.message]
+        assert len(logged) == 1 and logged[0].exc_info is not None
+    finally:
+        kops.FORCE = old_force
+        br.reset()
+
+
 def test_kernel_breaker_failed_probe_reopens():
     br = kops.BREAKER
     br.reset()

@@ -291,7 +291,7 @@ def test_eqrange_owned_pallas_matches_masking_path(n_shards, my_shard, rng):
     n, q = 800, 257  # non-tile-multiple query count
     keys = np.sort(rng.integers(0, 3000, n)).astype(np.int64)
     queries = rng.integers(-5, 3005, q).astype(np.int64)
-    subjects = rng.integers(0, 1 << 40, q).astype(np.int64)
+    subjects = rng.integers(-2**31, 2**31, q).astype(np.int32)
     lo_p, hi_p, own_p = eqrange_owned_pallas(
         jnp.asarray(keys), jnp.asarray(queries), jnp.asarray(subjects),
         my_shard, n_shards, interpret=True)
@@ -313,7 +313,7 @@ def test_eqrange_owned_dispatch_parity(rng):
     n, q = 500, 128
     keys = np.sort(rng.integers(0, 2000, n)).astype(np.int64)
     queries = rng.integers(0, 2000, q).astype(np.int64)
-    subjects = rng.integers(0, 1 << 40, q).astype(np.int64)
+    subjects = rng.integers(-2**31, 2**31, q).astype(np.int32)
     outs = {}
     old = kops.FORCE
     try:
