@@ -78,7 +78,7 @@ def sched_vs_serial(load: str, n_clients: int, interface: str = "spf",
     fragment-cache hit rate, measured occupancy, per-query latency
     quantiles (from the registry's ``sched.query_latency_s`` histogram,
     observed with registry-only observability enabled around the measured
-    pass — no tracer, so no fences perturb the wall), and the
+    pass — no in-memory tracer perturbs the wall), and the
     byte-identity flag the acceptance gate checks.  Compile cost is paid
     before timing on both paths (one warm pass each; measured rates come
     from a registry snapshot diff over the measured pass only, so the
@@ -112,7 +112,7 @@ def sched_vs_serial(load: str, n_clients: int, interface: str = "spf",
     sched.serve(stream)  # warm compile of the unit steps
     sched.cache.clear()
     base = sched.snapshot()
-    with obs.tracing(trace=False):  # registry-only: latency, no fences
+    with obs.tracing(trace=False):  # registry-only: latency, no tracer
         t0 = time.perf_counter()
         sched_out = sched.serve(stream)
         sched_s = time.perf_counter() - t0
@@ -218,7 +218,7 @@ def endpoint_serve(load: str, n_clients: int, interface: str = "endpoint",
 
     Returns a record with measured queries/min, request-latency p50/p99
     (from the obs-gated ``endpoint.latency_s`` histogram, registry-only
-    observability — no tracer fences), the cache-service hit rate,
+    observability — no in-memory tracer), the cache-service hit rate,
     interface NRS/NTB and the failure-model columns (timeouts, shed,
     drain faults/retries) — all read from ``sched.snapshot()`` diffs
     over the measured pass, plus the byte-identity flag against the
@@ -278,7 +278,7 @@ def endpoint_serve(load: str, n_clients: int, interface: str = "endpoint",
     else:
         chaos = contextlib.nullcontext()
     base = sched.snapshot()
-    with chaos, obs.tracing(trace=False):  # registry-only: no fences
+    with chaos, obs.tracing(trace=False):  # registry-only: no tracer
         t0 = time.perf_counter()
         resps = svc.serve(reqs)
         wall = time.perf_counter() - t0
@@ -408,7 +408,7 @@ def live_ingest_serve(load: str, n_clients: int, rate_pct: float,
     base_snap = sched.snapshot()
     live_s = ingest_s = 0.0
     compactions = 0
-    with obs.tracing(trace=False):  # registry-only: latency, no fences
+    with obs.tracing(trace=False):  # registry-only: latency, no tracer
         for _ in range(rounds):
             batch = make_batch(live)
             batches.append(batch)

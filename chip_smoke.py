@@ -308,7 +308,7 @@ def one_chip(seed: int, dev, clock) -> None:
     from repro.endpoint.service import EndpointService, ServiceConfig
 
     g, store = build_store(seed)
-    obs.enable(trace=False)  # registry only: dispatch tallies, no fences
+    obs.enable(trace=False)  # registry only: dispatch tallies, no tracer
     qs, rows, _ = pick_queries(g, store, clock, PER_LOAD)
     texts = [to_sparql(q) for q in qs]
     want = dict(zip(texts, rows))

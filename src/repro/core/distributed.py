@@ -89,7 +89,8 @@ class DistConfig:
 
 def make_batch_step(lane_fn, out_proto=None, *, mesh: Mesh | None = None,
                     data_axis: str | None = "data",
-                    lane_axes: tuple[str, ...] = ("model",)):
+                    lane_axes: tuple[str, ...] = ("model",),
+                    name: str = "step"):
     """Lift a per-lane evaluator into one jitted batch step (the shared
     step factory behind both engines and the scheduler's mesh waves).
 
@@ -117,14 +118,15 @@ def make_batch_step(lane_fn, out_proto=None, *, mesh: Mesh | None = None,
     structure (leaf values are ignored) so the factory can derive
     ``shard_map`` out_specs.  Either way the lane evaluator is written
     once and lowers under all three — the collective schedule (or its
-    absence) is the only difference.
+    absence) is the only difference.  ``name`` names the jitted program
+    (``jit_<name>`` in the profiler's trace).
     """
     if mesh is None:
         def step(dev: StoreArrays, *lane_args):
             in_axes = (None,) + (0,) * len(lane_args)
             return jax.vmap(lane_fn, in_axes=in_axes)(dev, *lane_args)
 
-        return jax.jit(step)
+        return jax.jit(_named(step, name))
 
     if out_proto is None:
         raise ValueError("mesh-mapped steps need out_proto for out_specs")
@@ -146,7 +148,13 @@ def make_batch_step(lane_fn, out_proto=None, *, mesh: Mesh | None = None,
         return _shard_map(shard_fn, mesh, in_specs, out_specs)(
             stacked, *lane_batches)
 
-    return jax.jit(step)
+    return jax.jit(_named(step, name))
+
+
+def _named(fn, name: str):
+    """``fn`` renamed, so that its jitted program is ``jit_<name>``."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
 
 
 def _lane_eval(plans: tuple, n_vars: int, cfg: DistConfig, radix: int,

@@ -117,16 +117,22 @@ TPF-vs-SPF server-op comparisons track the kernel layer actually serving
 the requests.
 
 Observability: with ``repro.obs`` enabled, this execution model is
-recorded live as a span hierarchy — ``sched.drain`` → ``wave``
-(lowering, width, cap) → ``unit`` → ``cache.probe`` / ``wave.lower`` /
-``unit.step`` / ``cache.replay_device`` / ``gather.merge`` /
-``overflow.resume``, plus per-query ``query`` async spans riding across
-waves, ``engine.query`` → ``unit`` → ``unit.step`` on the single-query
-path, and ``kernel.*`` instants marking trace-time backend dispatch.
-``obs.tracer.export_chrome`` writes a Perfetto-loadable timeline; every
-counter in this module's components is a named instrument in the same
-registry (``QueryScheduler.snapshot``).  Off by default at zero
-overhead — the traced and untraced executions are byte-identical.
+recorded live as ``obs.span`` layer boundaries — ``sched.drain`` →
+``sched.plan``, ``wave`` (lowering, width, cap, wave number) → ``unit``
+→ ``cache.probe`` / ``wave.lower`` / ``unit.step`` (dispatch) /
+``unit.wait`` (the device's time) / ``unit.pull`` / ``cache.insert`` /
+``cache.replay_device`` / ``overflow.resume`` / ``unit.account``, then
+``wave.finalize``; ``engine.query`` → ``unit`` → ``unit.step`` on the
+single-query path.  Each span lands in the JAX profiler's host plane
+(on the device's clock) and sums its seconds into the owning registry
+(``sched.span_s.<name>``); an attached ``SpanTracer`` also keeps the
+per-query ``query`` async spans, ``gather.merge`` and the ``kernel.*``
+instants marking trace-time backend dispatch, and
+``obs.tracer.export_chrome`` writes them as a Perfetto-loadable
+timeline.  Every counter in this module's components is a named
+instrument in the same registry (``QueryScheduler.snapshot``).  Off by
+default at zero overhead, and no span blocks on the device — the traced
+and untraced executions are byte-identical.
 """
 
 from __future__ import annotations
@@ -373,19 +379,15 @@ class QueryEngine:
         """
         if not obs.enabled:
             return self._run(bgp)
-        tr = obs.tracer
-        sp = tr.begin("engine.query",
-                      interface=self.cfg.interface) if tr else None
-        t0 = time.perf_counter()
-        table, stats = self._run(bgp)
-        # the latency histogram lives in the *global* obs registry (the
-        # engine has no per-instance registry); obs-gated, so the
-        # disabled path never mutates it
-        obs.registry.observe("engine.query_latency_s",
-                             time.perf_counter() - t0)
-        if sp:
-            tr.end(sp, fence=(table.rows, table.valid),
-                   n_results=stats.n_results)
+        with obs.span("engine.query", interface=self.cfg.interface) as sp:
+            t0 = time.perf_counter()
+            table, stats = self._run(bgp)
+            # the latency histogram lives in the *global* obs registry (the
+            # engine has no per-instance registry); obs-gated, so the
+            # disabled path never mutates it
+            obs.registry.observe("engine.query_latency_s",
+                                 time.perf_counter() - t0)
+            sp.note(n_results=stats.n_results)
         return table, stats
 
     def _run(self, bgp: BGP) -> tuple[BindingTable, QueryStats]:
@@ -444,7 +446,6 @@ class QueryEngine:
         through their tail; byte-safe by capacity-independence)."""
         from repro.core import stepper
 
-        tr = obs.tracer
         cfg = self.cfg
         store = self.store
         dev = store.device
@@ -466,57 +467,51 @@ class QueryEngine:
         max_peak = 1
         nrs = ntb = server = client = 0
         for k, up in enumerate(plan.units):
-            usp = tr.begin("unit", k=k) if tr else None
-            # once overflow latches (at max_cap) the blind ladder's give-up
-            # rung runs everything at max_cap on the truncated table — do
-            # exactly that for byte-identity
-            want = cfg.max_cap if overflow \
-                else self.planner.unit_start_cap(plan, k, n_in)
-            if want != cap:
-                rows, valid = stepper.reseat(rows, valid, want)
-                cap = want
-            while True:
-                step = stepper.serial_unit_step(up, store.radix, logn)
-                ssp = tr.begin("unit.step", k=k, cap=cap) if tr else None
-                r_o, v_o, o_o, ops_o, cnt_o, peak_o = step(
-                    dev, const_vec, rows[None], valid[None],
-                    jnp.asarray([overflow]))
-                if ssp:
-                    tr.end(ssp, fence=(r_o, v_o))
-                unit_ovf = bool(np.asarray(o_o)[0])
-                if unit_ovf and not overflow and cap < cfg.max_cap:
-                    # resumable overflow: regrow only this unit's table,
-                    # seeded with the checkpointed (pre-step) prefix
-                    rsp = tr.begin("overflow.resume", unit=k,
-                                   cap=cap) if tr else None
-                    cap = min(cap * 4, cfg.max_cap)
-                    rows, valid = stepper.reseat(rows, valid, cap)
-                    if rsp:
-                        tr.end(rsp)
-                    continue
-                break
-            rows, valid, ovf_dev = r_o[0], v_o[0], o_o[0]
-            out_count = int(np.asarray(cnt_o)[0])
-            d = stepper.unit_cost(cfg, k, up, n_in,
-                                  out_count, int(np.asarray(ops_o)[0]),
-                                  probe_ops)
-            nrs += d[0]
-            ntb += d[1]
-            server += d[2]
-            client += d[3]
-            if not unit_ovf:
-                # record what the unit NEEDED (its true peak row count),
-                # not the capacity it happened to run at — warm runs then
-                # get exactly-right-sized tables even where the chained
-                # oracle bound (a monotone product) overshoots
-                peak = int(np.asarray(peak_o)[0])
-                self.planner.observe_unit(
-                    plan, k, self.planner.snug(max(peak, n_in)))
-                max_peak = max(max_peak, peak, n_in)
-            overflow = unit_ovf
-            n_in = out_count
-            if usp:
-                tr.end(usp, fence=(rows, valid), n_out=out_count)
+            with obs.span("unit", k=k) as usp:
+                # once overflow latches (at max_cap) the blind ladder's give-up
+                # rung runs everything at max_cap on the truncated table — do
+                # exactly that for byte-identity
+                want = cfg.max_cap if overflow \
+                    else self.planner.unit_start_cap(plan, k, n_in)
+                if want != cap:
+                    rows, valid = stepper.reseat(rows, valid, want)
+                    cap = want
+                while True:
+                    step = stepper.serial_unit_step(up, store.radix, logn)
+                    with obs.span("unit.step", k=k, cap=cap):
+                        r_o, v_o, o_o, ops_o, cnt_o, peak_o = step(
+                            dev, const_vec, rows[None], valid[None],
+                            jnp.asarray([overflow]))
+                    unit_ovf = bool(np.asarray(o_o)[0])
+                    if unit_ovf and not overflow and cap < cfg.max_cap:
+                        # resumable overflow: regrow only this unit's table,
+                        # seeded with the checkpointed (pre-step) prefix
+                        with obs.span("overflow.resume", unit=k, cap=cap):
+                            cap = min(cap * 4, cfg.max_cap)
+                            rows, valid = stepper.reseat(rows, valid, cap)
+                        continue
+                    break
+                rows, valid, ovf_dev = r_o[0], v_o[0], o_o[0]
+                out_count = int(np.asarray(cnt_o)[0])
+                d = stepper.unit_cost(cfg, k, up, n_in,
+                                      out_count, int(np.asarray(ops_o)[0]),
+                                      probe_ops)
+                nrs += d[0]
+                ntb += d[1]
+                server += d[2]
+                client += d[3]
+                if not unit_ovf:
+                    # record what the unit NEEDED (its true peak row count),
+                    # not the capacity it happened to run at — warm runs then
+                    # get exactly-right-sized tables even where the chained
+                    # oracle bound (a monotone product) overshoots
+                    peak = int(np.asarray(peak_o)[0])
+                    self.planner.observe_unit(
+                        plan, k, self.planner.snug(max(peak, n_in)))
+                    max_peak = max(max_peak, peak, n_in)
+                overflow = unit_ovf
+                n_in = out_count
+                usp.note(n_out=out_count)
 
         n_results = n_in
         if cfg.interface == "endpoint":

@@ -101,6 +101,7 @@ from collections import OrderedDict
 from dataclasses import dataclass, replace
 from typing import Iterable, NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
@@ -244,6 +245,13 @@ class SchedMetrics(obs.RegistryView):
         # finalize excluded).  The device-replay invariant the tests pin:
         # an all-hit wave adds zero.
         "host_block_pulls",
+        # every blocking device->host transfer of a wave (each np.asarray
+        # of a device array: per unit the digest, the step's four stat
+        # arrays and two per inserted miss; the sharded occupancy pull,
+        # overflow checkpoints, and finalize's two).  A host round trip
+        # each, whatever the payload: what bounds the host's share of a
+        # unit step
+        "host_syncs",
         # bytes moved by the sharded lowering's per-unit gather
         # collectives (benchlib folds these into the modeled throughput
         # so sharded BENCH numbers are not silently optimistic)
@@ -546,12 +554,47 @@ class QueryScheduler:
 
         tr = obs.tracer
         if tr:
-            dspan = tr.begin("sched.drain", requests=len(requests))
             for req in requests:
                 # per-query lifetime as an async span (queries overlap
                 # waves freely); closed at finalize in _run_wave
                 tr.begin_async("query", req.rid, client=req.client)
 
+        try:
+            with obs.span("sched.drain", self.metrics,
+                          requests=len(requests)):
+                with obs.span("sched.plan", self.metrics):
+                    buckets = self._bucket(requests)
+                while buckets:
+                    (sig, cap, k0, _vep), jobs = buckets.popitem(last=False)
+                    lanes = self.scfg.lanes
+                    for i in range(0, len(jobs), lanes):
+                        wave = jobs[i:i + lanes]
+                        retries = self._run_wave(wave, results)
+                        for job in retries:
+                            # the pinned view epoch keys the bucket so
+                            # retries from different epochs never share
+                            # one wave
+                            vep = job.view.epoch if job.view is not None \
+                                else None
+                            buckets.setdefault(
+                                (sig, job.cap, job.resume_k, vep),
+                                []).append(job)
+                        # wave boundary: writes queued while serving land
+                        # here — the wave that just finished served its
+                        # pinned view, the next wave (and its retries, via
+                        # the pins) stays torn-free
+                        self._apply_writes()
+        finally:
+            self._draining = False
+        self._t_submit.clear()  # unconditional: no leak across obs toggles
+        self._deadlines.clear()
+        return results
+
+    def _bucket(self, requests: list[Request]
+                ) -> OrderedDict[tuple, list[_Job]]:
+        """Plan the drain's requests and bucket them by (signature, cap,
+        resume unit, pinned epoch), collapsing identical in-flight
+        queries onto one job."""
         # store mutated since the cache/planner last swept: reconcile
         # fragments and high-water marks now (keys are epoch-tagged, so
         # they could never alias — this reclaims touched entries' memory
@@ -559,9 +602,6 @@ class QueryScheduler:
         # epoch; the sweep state lives on the pod-shared objects so fresh
         # schedulers still trigger it)
         self._sync_components()
-
-        # bucket by (signature, cap, resume unit); collapse identical
-        # in-flight queries
         buckets: OrderedDict[tuple, list[_Job]] = OrderedDict()
         job_of: dict[tuple, _Job] = {}
         for req in requests:
@@ -577,32 +617,7 @@ class QueryScheduler:
                 self.metrics.jobs += 1
             else:
                 job.rids.append(req.rid)
-
-        try:
-            while buckets:
-                (sig, cap, k0, _vep), jobs = buckets.popitem(last=False)
-                lanes = self.scfg.lanes
-                for i in range(0, len(jobs), lanes):
-                    wave = jobs[i:i + lanes]
-                    retries = self._run_wave(wave, results)
-                    for job in retries:
-                        # the pinned view epoch keys the bucket so retries
-                        # from different epochs never share one wave
-                        vep = job.view.epoch if job.view is not None else None
-                        buckets.setdefault((sig, job.cap, job.resume_k, vep),
-                                           []).append(job)
-                    # wave boundary: writes queued while serving land
-                    # here — the wave that just finished served its
-                    # pinned view, the next wave (and its retries, via
-                    # the pins) stays torn-free
-                    self._apply_writes()
-        finally:
-            self._draining = False
-        if tr:
-            tr.end(dspan)
-        self._t_submit.clear()  # unconditional: no leak across obs toggles
-        self._deadlines.clear()
-        return results
+        return buckets
 
     # ------------------------------------------------------------ deadlines
     def _job_deadline(self, job: _Job) -> float | None:
@@ -638,7 +653,8 @@ class QueryScheduler:
                 if t0 is not None:
                     self.registry.observe("sched.query_latency_s", t1 - t0)
                 if tr:
-                    tr.end_async("query", rid, expired=True)
+                    tr.end_async("query", rid, expired=True,
+                                 wave=self.metrics.waves)
 
     def _wave_shard_trim(self, jobs: list[_Job], active: list[int],
                          k: int, cap: int) -> int:
@@ -691,10 +707,13 @@ class QueryScheduler:
         (uploaded deltas), and Omega blocks cross to the host only for a
         miss's recorded prefix or an overflow-retire's checkpoint
         (counted in ``metrics.host_block_pulls``) — and once at finalize
-        to deliver the responses.
+        to deliver the responses.  Every blocking pull, the small ones
+        included, counts in ``metrics.host_syncs``; each host phase is an
+        ``obs.span`` (``cache.probe``, ``unit.step`` for the dispatch,
+        ``unit.wait`` for the device time, ``unit.pull``,
+        ``cache.insert``, ``unit.account``, ``wave.finalize``).
         """
         scfg = self.scfg
-        tr = obs.tracer
         plan, cap = jobs[0].plan, jobs[0].cap
         k0 = jobs[0].resume_k
         n_active = len(jobs)
@@ -766,9 +785,7 @@ class QueryScheduler:
         acc = [job.acc if job.acc is not None else _LaneAcc()
                for job in jobs]
         self.metrics.waves += 1
-        wsp = tr.begin("wave", lowering=lowering, latch=bool(latch),
-                       cap=cap, width=B, jobs=n_active, resume_k=k0,
-                       units=len(plan.units) - k0) if tr else None
+        wave_no = self.metrics.waves
 
         # wave state is device-resident for the whole wave; host numpy
         # exists only in the seeds above and the finalize pull below
@@ -780,273 +797,321 @@ class QueryScheduler:
 
         def _retire(j: int, k: int) -> None:
             job = jobs[j]
-            rsp = tr.begin("overflow.resume", unit=k, cap=cap,
-                           rid=job.rids[0]) if tr else None
-            # the checkpointed seed: this lane's pre-step valid prefix
-            # (rows_d still holds the unit's input state at both call
-            # sites) — one counted Omega-block pull
-            seed = np.asarray(rows_d[j, :n_in[j]])
-            self.metrics.host_block_pulls += 1
-            retries.append(_Job(job.plan, job.consts,
-                                min(cap * 4, self.cfg.max_cap), job.rids,
-                                resume_k=k, seed=seed, acc=acc[j],
-                                peak_seen=job.peak_seen))
-            retired.add(j)
-            self.metrics.retries += 1
-            if rsp:
-                tr.end(rsp)
+            with obs.span("overflow.resume", self.metrics, unit=k, cap=cap,
+                          rid=job.rids[0]):
+                # the checkpointed seed: this lane's pre-step valid prefix
+                # (rows_d still holds the unit's input state at both call
+                # sites) — one counted Omega-block pull
+                seed = np.asarray(rows_d[j, :n_in[j]])
+                self.metrics.host_block_pulls += 1
+                self.metrics.host_syncs += 1
+                retries.append(_Job(job.plan, job.consts,
+                                    min(cap * 4, self.cfg.max_cap), job.rids,
+                                    resume_k=k, seed=seed, acc=acc[j],
+                                    peak_seen=job.peak_seen))
+                retired.add(j)
+                self.metrics.retries += 1
 
-        for k in range(k0, len(plan.units)):
-            up = plan.units[k]
-            io = unit_io(up)
-            active = [j for j in range(n_active) if j not in retired]
-            # cooperative deadline check: a job whose every rid has an
-            # expired absolute deadline is answered (None, stats-so-far)
-            # here, at the unit boundary, instead of burning the rest of
-            # the wave (the remaining lanes step on without it)
-            if active and self._deadlines:
-                now = time.perf_counter()
-                for j in list(active):
-                    dl = self._job_deadline(jobs[j])
-                    if dl is not None and now >= dl:
-                        self._expire(jobs[j], acc[j], bool(ovf[j]), results)
-                        retired.add(j)
-                        active.remove(j)
-            if not active:
-                break
-            n_in = {j: counts[j] for j in active}
+        with obs.span("wave", self.metrics, wave=wave_no, lowering=lowering,
+                      latch=bool(latch), cap=cap, width=B, jobs=n_active,
+                      resume_k=k0, units=len(plan.units) - k0) as wsp:
+            for k in range(k0, len(plan.units)):
+                up = plan.units[k]
+                io = unit_io(up)
+                active = [j for j in range(n_active) if j not in retired]
+                # cooperative deadline check: a job whose every rid has an
+                # expired absolute deadline is answered (None,
+                # stats-so-far) here, at the unit boundary, instead of
+                # burning the rest of the wave (the remaining lanes step
+                # on without it)
+                if active and self._deadlines:
+                    now = time.perf_counter()
+                    for j in list(active):
+                        dl = self._job_deadline(jobs[j])
+                        if dl is not None and now >= dl:
+                            self._expire(jobs[j], acc[j], bool(ovf[j]),
+                                         results)
+                            retired.add(j)
+                            active.remove(j)
+                if not active:
+                    break
+                n_in = {j: counts[j] for j in active}
 
-            # --- cache phase: digest-first canonicalization ---------------
-            # the digest is a pure function of the valid prefix, which is
-            # byte-identical across lowerings and shard counts, so sharded
-            # waves hit fragments recorded by vmap waves and vice versa
-            usp = tr.begin("unit", k=k, lanes=len(active)) if tr else None
-            status: dict[int, tuple[str, object]] = {}
-            keys: dict[int, tuple] = {}
-            if scfg.use_cache:
-                csp = tr.begin("cache.probe") if tr else None
-                d = np.asarray(
-                    stepper.digest_step(io.read_cols, *lane_layout)(
-                        rows_d, valid_d))
-                digs = {j: tuple(int(x) for x in d[j]) for j in active}
-                first_of: dict[tuple, int] = {}
-                for j in active:
-                    cvals = tuple(int(consts[j, i]) for i in io.const_idx)
-                    key = unit_digest_key(io, cvals, cap, epoch, n_in[j],
-                                          digs[j])
-                    keys[j] = key
-                    if key in first_of:
-                        status[j] = ("shared", first_of[key])
-                        self.cache.note_shared_hit()
-                        continue
-                    entry = self.cache.get(key, epoch)
-                    if entry is None:
-                        first_of[key] = j
-                        status[j] = ("miss", None)
+                with obs.span("unit", self.metrics, k=k,
+                              lanes=len(active)) as usp:
+                    if scfg.use_cache:
+                        status, keys = self._probe_cache(
+                            io, active, consts, cap, epoch, n_in, rows_d,
+                            valid_d, lane_layout)
                     else:
-                        status[j] = ("hit", entry)
-                if csp:
-                    tr.end(csp, lanes=len(active),
-                           hits=sum(1 for s, _ in status.values()
-                                    if s != "miss"))
-            else:
-                status = {j: ("miss", None) for j in active}
+                        status = {j: ("miss", None) for j in active}
+                        keys = {}
+                    need_step = any(s == "miss" for s, _ in status.values())
+                    ops_lane: dict[int, int] = {}
+                    if need_step:
+                        with obs.span("wave.lower", self.metrics,
+                                      lowering=lowering):
+                            step = self._lower(up, view, jobs, active, k,
+                                               cap, B, V, lowering, latch)
+                        if faults.plan is not None:
+                            faults.hit("unit.step", sig=plan.signature, k=k)
+                        with obs.span("unit.step", self.metrics, k=k):
+                            out = step(dev, consts_dev, rows_d, valid_d,
+                                       jnp.asarray(ovf))
+                        # the host waits here for the step's device time;
+                        # the pulls below would block on it anyway
+                        with obs.span("unit.wait", self.metrics, k=k):
+                            jax.block_until_ready(out)
+                        # the sharded step returns an 8th output (the pmax
+                        # of per-shard row counts) that feeds the occupancy
+                        # trims; the vmap/replicated steps return the
+                        # common 7
+                        r_o, v_o, o_o, src_o, ops_o, cnt_o, peak_o = out[:7]
+                        with obs.span("unit.pull", self.metrics):
+                            ops_np = np.asarray(ops_o)
+                            cnt_np = np.asarray(cnt_o)
+                            ovf_np = np.asarray(o_o)
+                            peak_np = np.asarray(peak_o)
+                            self.metrics.host_syncs += 4
+                        self.metrics.steps += 1
+                        self.metrics.lane_steps += B
+                        self.metrics.active_lane_steps += len(active)
+                        for j in active:
+                            ops_lane[j] = int(ops_np[j])
+                            if bool(ovf_np[j]) and not bool(ovf[j]) \
+                                    and cap < self.cfg.max_cap:
+                                # resumable overflow: checkpoint this unit's
+                                # input prefix (still the pre-step device
+                                # state) and requeue at 4x — units 0..k-1
+                                # are never re-run
+                                _retire(j, k)
+                                continue
+                            if status[j][0] == "miss" and scfg.use_cache \
+                                    and not bool(ovf[j]) \
+                                    and epoch == self.store.epoch:
+                                # (a stale-pinned retry wave skips
+                                # insertion: its fragments describe a
+                                # superseded epoch and would only park dead
+                                # weight under an old-epoch key)
+                                self._insert(keys[j], io, j, r_o, src_o,
+                                             int(cnt_np[j]),
+                                             bool(ovf_np[j]),
+                                             int(ops_np[j]),
+                                             int(peak_np[j]), epoch)
+                        rows_d, valid_d = r_o, v_o
+                        ovf = np.array(ovf_np)
+                        for j in active:
+                            if j not in retired:
+                                counts[j] = int(cnt_np[j])
+                                jobs[j].peak_seen = max(jobs[j].peak_seen,
+                                                        int(peak_np[j]),
+                                                        n_in[j])
+                        if use_shard and not latch:
+                            # feed the measured per-shard occupancy back
+                            # into the planner so the next wave of this
+                            # unit trims its merge to what shards actually
+                            # produced.  Latch waves are skipped: their
+                            # pmax runs post-merge (the replicated global
+                            # count, not a per-shard block), and retired
+                            # lanes are skipped because a clamped table's
+                            # peak understates the true need
+                            with obs.span("unit.pull", self.metrics):
+                                sp = np.asarray(out[7])
+                                self.metrics.host_syncs += 1
+                            for j in active:
+                                if j not in retired:
+                                    self.planner.observe_shard_peak(
+                                        jobs[j].plan, k, self._n_shards,
+                                        int(sp[j]))
+                    else:
+                        # every active lane hit: replay the cached deltas
+                        # on the device (stepper.replay_step /
+                        # kops.replay_delta).  The uploaded delta is the
+                        # small object — the lanes' Omega blocks never
+                        # cross to the host, so an all-hit wave adds zero
+                        # host_block_pulls (the invariant the tests pin).
+                        self.metrics.steps_skipped += 1
+                        live: dict[int, FragmentEntry] = {}
+                        for j in active:
+                            entry = status[j][1]
+                            if isinstance(entry, int):  # alias of a hit
+                                entry = status[entry][1]
+                            assert isinstance(entry, FragmentEntry)
+                            if entry.overflow and not bool(ovf[j]) \
+                                    and cap < self.cfg.max_cap:
+                                # the cached unit overflowed at this cap:
+                                # resume from the checkpointed seed like a
+                                # computed one
+                                _retire(j, k)
+                                continue
+                            live[j] = entry
+                        if not live:  # every hit lane retired on overflow
+                            usp.note(path="replay", live=0)
+                            continue
+                        if faults.plan is not None:
+                            faults.hit("cache.replay", sig=plan.signature,
+                                       k=k)
+                        with obs.span("cache.replay_device", self.metrics,
+                                      lanes=len(live)):
+                            rows_d, valid_d = stepper.replay_step(
+                                io.write_cols, *lane_layout)(
+                                rows_d, *_replay_deltas(live, io, B, cap))
+                        for j, e in live.items():
+                            ovf[j] = bool(ovf[j]) | e.overflow
+                            counts[j] = e.n_out
+                            ops_lane[j] = e.ops
+                            jobs[j].peak_seen = max(jobs[j].peak_seen,
+                                                    e.peak, n_in[j])
 
-            need_step = any(s == "miss" for s, _ in status.values())
-            ops_lane: dict[int, int] = {}
-            if need_step:
-                lsp = tr.begin("wave.lower", lowering=lowering) if tr else None
-                if use_shard:
-                    # latch waves merge at the full cap (global truncation
-                    # must see every shard's rows); non-latch waves trim to
-                    # the measured shard occupancy when the planner has
-                    # observed this unit, else the static skew headroom
-                    trim = cap if latch else \
-                        self._wave_shard_trim(jobs, active, k, cap)
-                    step = stepper.sharded_unit_step(
-                        up, self.store.radix, self.mesh, self.data_axis,
-                        self._shard_lane_axes, self._n_shards, view.logn,
-                        trim, latch, scfg.shard_merge)
-                    self.metrics.mesh_steps += 1
-                    self.metrics.shard_steps += 1
-                    # the per-unit merge collective's payload (rows incl.
-                    # the provenance column + validity), for the
-                    # throughput model — measured, not assumed.  Latch
-                    # waves pay it once per branch (mid-unit merges)
-                    rounds = len(up.branches) if latch else 1
-                    g_bytes = (B * self._n_shards * trim
-                               * ((V + 1) * 4 + 1) * rounds)
-                    self.metrics.gather_bytes += g_bytes
-                    if scfg.shard_merge == "lexsort":
-                        self.metrics.merge_lexsort_steps += 1
-                    if tr:
-                        tr.instant("gather.merge",
-                                   strategy=("lexsort"
-                                             if scfg.shard_merge == "lexsort"
-                                             else "kway"),
-                                   bytes=g_bytes, trim=trim, rounds=rounds)
-                elif use_mesh:
-                    step = stepper.unit_step(up, self.store.radix, self.mesh,
-                                             self._lane_axes, logn=view.logn)
-                    self.metrics.mesh_steps += 1
-                else:
-                    step = stepper.unit_step(up, self.store.radix,
-                                             logn=view.logn)
-                if lsp:
-                    tr.end(lsp)
-                if faults.plan is not None:
-                    faults.hit("unit.step", sig=plan.signature, k=k)
-                ssp = tr.begin("unit.step", k=k) if tr else None
-                out = step(dev, consts_dev, rows_d, valid_d,
-                           jnp.asarray(ovf))
-                if ssp:
-                    tr.end(ssp, fence=out)
-                # the sharded step returns an 8th output (the pmax of
-                # per-shard row counts) that feeds the occupancy trims;
-                # the vmap/replicated steps return the common 7
-                r_o, v_o, o_o, src_o, ops_o, cnt_o, peak_o = out[:7]
-                ops_np = np.asarray(ops_o)
-                cnt_np = np.asarray(cnt_o)
-                ovf_np = np.asarray(o_o)
-                peak_np = np.asarray(peak_o)
-                self.metrics.steps += 1
-                self.metrics.lane_steps += B
-                self.metrics.active_lane_steps += len(active)
-                for j in active:
-                    ops_lane[j] = int(ops_np[j])
-                    if bool(ovf_np[j]) and not bool(ovf[j]) \
-                            and cap < self.cfg.max_cap:
-                        # resumable overflow: checkpoint this unit's input
-                        # prefix (still the pre-step device state) and
-                        # requeue at 4x — units 0..k-1 are never re-run
-                        _retire(j, k)
-                        continue
-                    if status[j][0] == "miss" and scfg.use_cache \
-                            and not bool(ovf[j]) \
-                            and epoch == self.store.epoch:
-                        # (a stale-pinned retry wave skips insertion: its
-                        # fragments describe a superseded epoch and would
-                        # only park dead weight under an old-epoch key)
-                        # miss that needs insertion: pull only this lane's
-                        # output prefix to record the replayable delta
-                        self.metrics.host_block_pulls += 1
-                        n_out = int(cnt_np[j])
-                        out_rows = np.asarray(r_o[j, :n_out])
-                        entry = FragmentEntry(
-                            src_row=np.ascontiguousarray(
-                                np.asarray(src_o[j, :n_out])),
-                            written=np.ascontiguousarray(
-                                out_rows[:, list(io.write_cols)]),
-                            overflow=bool(ovf_np[j]),
-                            ops=int(ops_np[j]),
-                            epoch=epoch,
-                            peak=int(peak_np[j]),
-                        )
-                        self.cache.put(keys[j], entry, epoch)
-                rows_d, valid_d = r_o, v_o
-                ovf = np.array(ovf_np)
-                for j in active:
-                    if j not in retired:
-                        counts[j] = int(cnt_np[j])
-                        jobs[j].peak_seen = max(jobs[j].peak_seen,
-                                                int(peak_np[j]), n_in[j])
-                if use_shard and not latch:
-                    # feed the measured per-shard occupancy back into the
-                    # planner so the next wave of this unit trims its
-                    # merge to what shards actually produced.  Latch
-                    # waves are skipped: their pmax runs post-merge (the
-                    # replicated global count, not a per-shard block), and
-                    # retired lanes are skipped because a clamped table's
-                    # peak understates the true need
-                    sp = np.asarray(out[7])
-                    for j in active:
-                        if j not in retired:
-                            self.planner.observe_shard_peak(
-                                jobs[j].plan, k, self._n_shards, int(sp[j]))
-            else:
-                # every active lane hit: replay the cached deltas on the
-                # device (stepper.replay_step / kops.replay_delta).  The
-                # uploaded delta is the small object — the lanes' Omega
-                # blocks never cross to the host, so an all-hit wave adds
-                # zero host_block_pulls (the invariant the tests pin).
-                self.metrics.steps_skipped += 1
-                live: dict[int, FragmentEntry] = {}
-                for j in active:
-                    entry = status[j][1]
-                    if isinstance(entry, int):  # shared alias of a hit lane
-                        entry = status[entry][1]
-                    assert isinstance(entry, FragmentEntry)
-                    if entry.overflow and not bool(ovf[j]) \
-                            and cap < self.cfg.max_cap:
-                        # the cached unit overflowed at this cap: resume
-                        # from the checkpointed seed like a computed one
-                        _retire(j, k)
-                        continue
-                    live[j] = entry
-                if not live:  # every hit lane retired on a cached overflow
-                    if usp:
-                        tr.end(usp, path="replay", live=0)
-                    continue
-                n_w = len(io.write_cols)
-                m = 1
-                for e in live.values():
-                    m = max(m, e.n_out)
-                # pow2-pad the delta width: bounds replay-step retraces
-                m = min(1 << (m - 1).bit_length(), cap)
-                src_h = np.zeros((B, m), np.int32)
-                wr_h = np.zeros((B, m, n_w), np.int32)
-                nout_h = np.zeros((B,), np.int32)  # non-hit lanes: empty
-                for j, e in live.items():
-                    if e.n_out:
-                        src_h[j, :e.n_out] = e.src_row
-                        if n_w:
-                            wr_h[j, :e.n_out] = e.written
-                    nout_h[j] = e.n_out
-                if faults.plan is not None:
-                    faults.hit("cache.replay", sig=plan.signature, k=k)
-                psp = tr.begin("cache.replay_device",
-                               lanes=len(live)) if tr else None
-                rows_d, valid_d = stepper.replay_step(
-                    io.write_cols, *lane_layout)(
-                    rows_d, jnp.asarray(src_h), jnp.asarray(wr_h),
-                    jnp.asarray(nout_h))
-                if psp:
-                    tr.end(psp, fence=(rows_d, valid_d))
-                for j, e in live.items():
-                    ovf[j] = bool(ovf[j]) | e.overflow
-                    counts[j] = e.n_out
-                    ops_lane[j] = e.ops
-                    jobs[j].peak_seen = max(jobs[j].peak_seen, e.peak,
-                                            n_in[j])
+                    # --- host stats accounting (twin of engine._execute) --
+                    with obs.span("unit.account", self.metrics):
+                        for j in active:
+                            if j not in retired:
+                                self._account(acc[j], k, up, n_in[j],
+                                              counts[j], ops_lane[j],
+                                              status[j][0], view.probe_ops)
+                    usp.note(path="step" if need_step else "replay")
 
-            # --- host stats accounting (twin of engine._execute) ----------
+            # ------------------------------------------------------ finalize
+            with obs.span("wave.finalize", self.metrics):
+                self._finalize(jobs, retired, rows_d, valid_d, ovf, counts,
+                               acc, plan, results, wave_no)
+            wsp.note(retries=len(retries))
+        return retries
+
+    def _probe_cache(self, io, active: list[int], consts: np.ndarray,
+                     cap: int, epoch: int, n_in: dict[int, int], rows_d,
+                     valid_d, lane_layout: tuple
+                     ) -> tuple[dict[int, tuple[str, object]],
+                                dict[int, tuple]]:
+        """The cache phase of one unit: digest-first canonicalization.
+
+        The wave's device fingerprint (``stepper.digest_step``) is a pure
+        function of each lane's valid prefix — byte-identical across
+        lowerings and shard counts, so sharded waves hit fragments
+        recorded by vmap waves and vice versa.  Returns each active
+        lane's status (``miss``, ``hit`` with its entry, or ``shared``
+        with the lane it aliases) and cache key."""
+        status: dict[int, tuple[str, object]] = {}
+        keys: dict[int, tuple] = {}
+        with obs.span("cache.probe", self.metrics) as csp:
+            d = np.asarray(stepper.digest_step(io.read_cols, *lane_layout)(
+                rows_d, valid_d))
+            self.metrics.host_syncs += 1
+            first_of: dict[tuple, int] = {}
             for j in active:
-                if j in retired:
+                cvals = tuple(int(consts[j, i]) for i in io.const_idx)
+                key = unit_digest_key(io, cvals, cap, epoch, n_in[j],
+                                      tuple(int(x) for x in d[j]))
+                keys[j] = key
+                if key in first_of:
+                    status[j] = ("shared", first_of[key])
+                    self.cache.note_shared_hit()
                     continue
-                nrs_d, ntb_d, server_d, client_d = stepper.unit_cost(
-                    self.cfg, k, up, n_in[j], counts[j], ops_lane[j],
-                    view.probe_ops)
-                a = acc[j]
-                a.nrs += nrs_d
-                a.ntb += ntb_d
-                a.server += server_d
-                a.client += client_d
-                if status[j][0] == "miss":
-                    a.misses += 1
+                entry = self.cache.get(key, epoch)
+                if entry is None:
+                    first_of[key] = j
+                    status[j] = ("miss", None)
                 else:
-                    a.hits += 1
-                    a.nrs_saved += nrs_d
-                    a.ntb_saved += ntb_d
-            if usp:
-                tr.end(usp, fence=(rows_d, valid_d),
-                       path="step" if need_step else "replay")
+                    status[j] = ("hit", entry)
+            if obs.tracer:
+                csp.note(lanes=len(active),
+                         hits=sum(1 for s, _ in status.values()
+                                  if s != "miss"))
+        return status, keys
 
-        # --------------------------------------------------------- finalize
-        # the one end-of-wave materialisation: delivering the responses
-        # (deliberately not counted in host_block_pulls, which tracks
-        # unit-stepping traffic)
+    def _lower(self, up, view: _EpochView, jobs: list[_Job],
+               active: list[int], k: int, cap: int, B: int, V: int,
+               lowering: str, latch: bool):
+        """The wave's jitted step for unit ``up`` under its lowering."""
+        scfg = self.scfg
+        if lowering == "shard":
+            # latch waves merge at the full cap (global truncation must
+            # see every shard's rows); non-latch waves trim to the
+            # measured shard occupancy when the planner has observed this
+            # unit, else the static skew headroom
+            trim = cap if latch else \
+                self._wave_shard_trim(jobs, active, k, cap)
+            step = stepper.sharded_unit_step(
+                up, self.store.radix, self.mesh, self.data_axis,
+                self._shard_lane_axes, self._n_shards, view.logn,
+                trim, latch, scfg.shard_merge)
+            self.metrics.mesh_steps += 1
+            self.metrics.shard_steps += 1
+            # the per-unit merge collective's payload (rows incl. the
+            # provenance column + validity), for the throughput model —
+            # measured, not assumed.  Latch waves pay it once per branch
+            # (mid-unit merges)
+            rounds = len(up.branches) if latch else 1
+            g_bytes = (B * self._n_shards * trim
+                       * ((V + 1) * 4 + 1) * rounds)
+            self.metrics.gather_bytes += g_bytes
+            if scfg.shard_merge == "lexsort":
+                self.metrics.merge_lexsort_steps += 1
+            tr = obs.tracer
+            if tr:
+                tr.instant("gather.merge",
+                           strategy=("lexsort"
+                                     if scfg.shard_merge == "lexsort"
+                                     else "kway"),
+                           bytes=g_bytes, trim=trim, rounds=rounds)
+            return step
+        if lowering == "mesh":
+            self.metrics.mesh_steps += 1
+            return stepper.unit_step(up, self.store.radix, self.mesh,
+                                     self._lane_axes, logn=view.logn)
+        return stepper.unit_step(up, self.store.radix, logn=view.logn)
+
+    def _insert(self, key: tuple, io, j: int, r_o, src_o, n_out: int,
+                overflow: bool, ops: int, peak: int, epoch: int) -> None:
+        """Record a missed lane's replayable delta: pull only this lane's
+        output prefix and provenance (one counted Omega-block pull, two
+        host syncs) and put the fragment in the cache."""
+        with obs.span("cache.insert", self.metrics):
+            self.metrics.host_block_pulls += 1
+            self.metrics.host_syncs += 2
+            out_rows = np.asarray(r_o[j, :n_out])
+            entry = FragmentEntry(
+                src_row=np.ascontiguousarray(np.asarray(src_o[j, :n_out])),
+                written=np.ascontiguousarray(
+                    out_rows[:, list(io.write_cols)]),
+                overflow=overflow,
+                ops=ops,
+                epoch=epoch,
+                peak=peak,
+            )
+            self.cache.put(key, entry, epoch)
+
+    def _account(self, a: _LaneAcc, k: int, up, n_in: int, n_out: int,
+                 ops: int, status: str, probe_ops: int) -> None:
+        """One lane's unit in its cost account (twin of
+        ``engine._execute``); a cache-served unit's cost counts as saved."""
+        nrs_d, ntb_d, server_d, client_d = stepper.unit_cost(
+            self.cfg, k, up, n_in, n_out, ops, probe_ops)
+        a.nrs += nrs_d
+        a.ntb += ntb_d
+        a.server += server_d
+        a.client += client_d
+        if status == "miss":
+            a.misses += 1
+        else:
+            a.hits += 1
+            a.nrs_saved += nrs_d
+            a.ntb_saved += ntb_d
+
+    def _finalize(self, jobs: list[_Job], retired: set[int], rows_d,
+                  valid_d, ovf: np.ndarray, counts: list[int],
+                  acc: list[_LaneAcc], plan: QueryPlan, results: dict,
+                  wave_no: int) -> None:
+        """Deliver the wave's finished jobs into ``results``.
+
+        The one end-of-wave materialisation (two host syncs, deliberately
+        not counted in ``host_block_pulls``, which tracks unit-stepping
+        traffic); also where the planner observes each query's need."""
+        tr = obs.tracer
         rows_h = np.asarray(rows_d)
         valid_h = np.asarray(valid_d)
+        self.metrics.host_syncs += 2
         for j, job in enumerate(jobs):
             if j in retired:
                 continue
@@ -1092,7 +1157,8 @@ class QueryScheduler:
                         self.registry.observe("sched.query_latency_s",
                                               t1 - t0)
                     if tr:
-                        tr.end_async("query", rid, n_results=n_results)
+                        tr.end_async("query", rid, n_results=n_results,
+                                     wave=wave_no)
             if len(job.rids) > 1:
                 # collapsed duplicates: whole response fanned out from the
                 # shared execution — every unit request cache-served
@@ -1102,6 +1168,25 @@ class QueryScheduler:
                                      nrs_saved=nrs, ntb_saved=ntb)
                 for rid in job.rids[1:]:
                     results[rid] = (table, dup)
-        if wsp:
-            tr.end(wsp, retries=len(retries))
-        return retries
+
+
+def _replay_deltas(live: dict[int, FragmentEntry], io, B: int, cap: int
+                   ) -> tuple:
+    """The replay step's uploads: each live lane's cached delta (source
+    rows, written columns, row count) padded to a power-of-two width,
+    which bounds the replay step's retraces."""
+    n_w = len(io.write_cols)
+    m = 1
+    for e in live.values():
+        m = max(m, e.n_out)
+    m = min(1 << (m - 1).bit_length(), cap)
+    src_h = np.zeros((B, m), np.int32)
+    wr_h = np.zeros((B, m, n_w), np.int32)
+    nout_h = np.zeros((B,), np.int32)  # non-hit lanes: empty
+    for j, e in live.items():
+        if e.n_out:
+            src_h[j, :e.n_out] = e.src_row
+            if n_w:
+                wr_h[j, :e.n_out] = e.written
+        nout_h[j] = e.n_out
+    return jnp.asarray(src_h), jnp.asarray(wr_h), jnp.asarray(nout_h)

@@ -133,11 +133,11 @@ def unit_step(up: UnitPlan, radix: int, mesh: Mesh | None = None,
                     jnp.sum(table.valid.astype(jnp.int64)), peak)
 
         if mesh is None:
-            step = make_batch_step(lane_fn)
+            step = make_batch_step(lane_fn, name="unit_step")
         else:
             step = make_batch_step(lane_fn, out_proto=(0,) * 7,
                                    mesh=mesh, data_axis=None,
-                                   lane_axes=lane_axes)
+                                   lane_axes=lane_axes, name="unit_step")
         _STEP_CACHE[key] = step
     return step
 
@@ -554,7 +554,8 @@ def sharded_unit_step(up: UnitPlan, radix: int, mesh: Mesh, data_axis: str,
                     peak, shard_peak)
 
         step = make_batch_step(lane_fn, out_proto=(0,) * 8, mesh=mesh,
-                               data_axis=data_axis, lane_axes=lane_axes)
+                               data_axis=data_axis, lane_axes=lane_axes,
+                               name="sharded_unit_step")
         _STEP_CACHE[key] = step
     return step
 
@@ -576,7 +577,7 @@ def serial_unit_step(up: UnitPlan, radix: int, logn: int | None = None):
             return (table.rows, table.valid, table.overflow, ops,
                     jnp.sum(table.valid.astype(jnp.int64)), peak)
 
-        step = make_batch_step(lane_fn)
+        step = make_batch_step(lane_fn, name="serial_unit_step")
         _STEP_CACHE[key] = step
     return step
 
@@ -610,12 +611,13 @@ def digest_step(read_cols: tuple[int, ...], mesh: Mesh | None = None,
     if fn is None:
         cols = jnp.asarray(read_cols, jnp.int32) if read_cols else None
 
-        def digest(rows, valid):
+        def wave_digest(rows, valid):
             block = jnp.take(rows, cols, axis=2) if cols is not None \
                 else rows[:, :, :0]
             return jax.vmap(kops.fingerprint_rows)(block, valid)
 
-        fn = _STEP_CACHE[key] = _on_lanes(digest, 2, 1, mesh, lane_axes)
+        fn = _STEP_CACHE[key] = _on_lanes(wave_digest, 2, 1, mesh,
+                                          lane_axes)
     return fn
 
 
@@ -639,12 +641,13 @@ def replay_step(write_cols: tuple[int, ...], mesh: Mesh | None = None,
     if fn is None:
         cols = tuple(write_cols)
 
-        def replay(rows, src, written, n_out):
+        def wave_replay(rows, src, written, n_out):
             return jax.vmap(
                 lambda r, s, w, n: kops.replay_delta(r, s, w, n, cols)
             )(rows, src, written, n_out)
 
-        fn = _STEP_CACHE[key] = _on_lanes(replay, 4, 2, mesh, lane_axes)
+        fn = _STEP_CACHE[key] = _on_lanes(wave_replay, 4, 2, mesh,
+                                          lane_axes)
     return fn
 
 

@@ -49,11 +49,13 @@ same name):
 
 Observability follows the repo's split: counts are per-service
 ``RegistryView`` instruments that tally regardless; latency histograms
-(``endpoint.queue_wait_s``, ``endpoint.latency_s``) and the
-``endpoint.batch`` / ``endpoint.request`` / retry/bisect spans are
-recorded only when ``obs.enabled`` — and the tracer module stays
-unimported when tracing is off (the CI import guard covers this module
-too).
+(``endpoint.queue_wait_s``, ``endpoint.latency_s``), the
+``endpoint.parse`` / ``endpoint.batch`` / ``endpoint.deliver`` /
+retry/bisect spans (``obs.span``: profiler annotations plus
+``endpoint.span_s.*`` seconds on the scheduler's registry) and the
+``endpoint.request`` async events are recorded only when ``obs.enabled``
+— and the tracer module stays unimported when tracing is off (the CI
+import guard covers this module too).
 """
 
 from __future__ import annotations
@@ -392,26 +394,22 @@ class EndpointService:
             if backoff_s > 0:
                 await asyncio.sleep(backoff_s)
             if len(pends) == 1:
-                span = tr.begin("endpoint.retry", seq=pends[0].seq,
-                                retries_left=retries_left) if tr else None
-                await self._serve_domain(pends, retries_left - 1,
-                                         backoff_s * 2)
-                if span:
-                    tr.end(span)
+                with obs.span("endpoint.retry", self.stats,
+                              seq=pends[0].seq, retries_left=retries_left):
+                    await self._serve_domain(pends, retries_left - 1,
+                                             backoff_s * 2)
                 return
             self.stats.drain_bisects += 1
             mid = len(pends) // 2
-            span = tr.begin("endpoint.bisect", left=mid,
-                            right=len(pends) - mid,
-                            retries_left=retries_left) if tr else None
-            await self._serve_domain(pends[:mid], retries_left - 1,
-                                     backoff_s * 2)
-            await self._serve_domain(pends[mid:], retries_left - 1,
-                                     backoff_s * 2)
-            if span:
-                tr.end(span)
+            with obs.span("endpoint.bisect", self.stats, left=mid,
+                          right=len(pends) - mid, retries_left=retries_left):
+                await self._serve_domain(pends[:mid], retries_left - 1,
+                                         backoff_s * 2)
+                await self._serve_domain(pends[mid:], retries_left - 1,
+                                         backoff_s * 2)
             return
-        self._deliver(pends, rids, results)
+        with obs.span("endpoint.deliver", self.stats, requests=len(pends)):
+            self._deliver(pends, rids, results)
 
     def _deliver(self, pends: list[_Pending], rids: list[int],
                  results: dict) -> None:
@@ -437,30 +435,29 @@ class EndpointService:
 
     async def _serve_wave(self, wave: list[_Pending]) -> None:
         t0 = time.perf_counter()
-        live = [p for p in wave if self._parse(p)]
+        with obs.span("endpoint.parse", self.stats, requests=len(wave)):
+            live = [p for p in wave if self._parse(p)]
         if not live:
             return
-        tr = obs.tracer if obs.enabled else None
-        span = tr.begin("endpoint.batch", requests=len(live)) if tr else None
         if obs.enabled:
             for p in live:
                 self.sched.registry.observe("endpoint.queue_wait_s",
                                             t0 - p.t_enq)
-        try:
-            await self._serve_domain(live, self.cfg.drain_retries,
-                                     self.cfg.drain_backoff_s)
-        finally:
-            # exactly-once backstop: whatever the fault domain could not
-            # resolve (including through an exception escaping it) is
-            # answered "error" here, so no future is ever stranded and
-            # no admission slot leaks (_finish is idempotent)
-            for p in live:
-                if p.future is None or not p.future.done():
-                    self.stats.errors += 1
-                    self._finish(p, EndpointResponse(
-                        p.req.client, "error", error="wave aborted"))
-            if tr:
-                tr.end(span)
+        with obs.span("endpoint.batch", self.stats, requests=len(live)):
+            try:
+                await self._serve_domain(live, self.cfg.drain_retries,
+                                         self.cfg.drain_backoff_s)
+            finally:
+                # exactly-once backstop: whatever the fault domain could
+                # not resolve (including through an exception escaping
+                # it) is answered "error" here, so no future is ever
+                # stranded and no admission slot leaks (_finish is
+                # idempotent)
+                for p in live:
+                    if p.future is None or not p.future.done():
+                        self.stats.errors += 1
+                        self._finish(p, EndpointResponse(
+                            p.req.client, "error", error="wave aborted"))
 
     async def run(self, until_idle: bool = False) -> None:
         """The service loop: wait for arrivals, pack a fair wave, serve.

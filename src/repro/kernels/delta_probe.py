@@ -125,6 +125,7 @@ def delta_probe_pallas(ins_keys: jnp.ndarray, tomb_pos: jnp.ndarray,
         out_specs=[col] * 4,
         out_shape=[jax.ShapeDtypeStruct((q_len, 1), jnp.int32)] * 4,
         interpret=interpret,
+        name="delta_probe",
     )(ins_p, tomb_p, qk_p, ql_p, qh_p)
     ins_lo, ins_hi, tomb_lo, tomb_hi = (o[:q, 0] for o in out)
     # a query key equal to the dtype max sees the key padding in `<=`;

@@ -122,6 +122,7 @@ def fingerprint_rows_pallas(block: jnp.ndarray, valid: jnp.ndarray,
         out_specs=pl.BlockSpec((4, r_tile), lambda i: (ZERO, ZERO)),
         out_shape=jax.ShapeDtypeStruct((4, r_tile), jnp.int32),
         interpret=interpret,
+        name="fingerprint",
     )(block_t, mask_p)
     acc = jnp.sum(jax.lax.bitcast_convert_type(acc, jnp.uint32), axis=1,
                   dtype=jnp.uint32)

@@ -195,6 +195,7 @@ def eqrange_owned_pallas(keys: jnp.ndarray, query_keys: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((q_len, 1), jnp.int32)] * 3,
         interpret=interpret,
+        name="owned_probe",
     )(shard, s_lo, s_hi, keys_p, queries_p)
     rank_lo, rank_hi, owned = rank_lo[:q, 0], rank_hi[:q, 0], owned[:q, 0]
     rank_hi = jnp.minimum(rank_hi, n)

@@ -117,6 +117,7 @@ def replay_delta_pallas(seed_rows: jnp.ndarray, src: jnp.ndarray,
         out_specs=pl.BlockSpec((n_vars, j_tile), lambda j, i: (ZERO, j)),
         out_shape=jax.ShapeDtypeStruct((n_vars, src_p.shape[1]), jnp.int32),
         interpret=interpret,
+        name="replay_delta",
     )(src_p, seed_p)
     out = out_t.T[:m]
     # shared tail (identical to the oracle): write-col overlay, dead-row

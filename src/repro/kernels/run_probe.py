@@ -152,6 +152,7 @@ def run_probe_pallas(values: jnp.ndarray, lo: jnp.ndarray, hi: jnp.ndarray,
         out_specs=[col, col],
         out_shape=[jax.ShapeDtypeStruct((r_len, 1), jnp.int32)] * 2,
         interpret=interpret,
+        name="run_probe",
     )(values_p, lo_p, hi_p, targets_p)
     return pos[:r, 0], contains[:r, 0] != 0
 
@@ -233,5 +234,6 @@ def run_probe_prefetch_pallas(values: jnp.ndarray, lo: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(lo_p.shape, jnp.int32)] * 2,
         interpret=interpret,
+        name="run_probe_prefetch",
     )(base, nwin, values_p, lo_p, hi_p, targets_p)
     return pos[:r, 0], contains[:r, 0] != 0

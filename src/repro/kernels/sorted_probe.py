@@ -115,6 +115,7 @@ def sorted_probe_pallas(keys: jnp.ndarray, queries: jnp.ndarray,
         out_specs=[col, col],
         out_shape=[jax.ShapeDtypeStruct((q_len, 1), jnp.int32)] * 2,
         interpret=interpret,
+        name="sorted_probe",
     )(keys_p, queries_p)
     rank_lo = rank_lo[:q, 0]
     rank_hi = jnp.minimum(rank_hi[:q, 0], n)

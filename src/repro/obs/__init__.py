@@ -1,4 +1,4 @@
-"""Unified observability: one metrics registry + an opt-in span tracer.
+"""Unified observability: one metrics registry + one span API.
 
 The serving stack's measured quantities (the paper's evaluation currency:
 throughput, p50/p99 latency, transferred bytes, server ops) live in
@@ -9,27 +9,39 @@ views over named instruments (``obs.registry.RegistryView``), and
 and the BENCH figures diff (``snap_b - snap_a``) instead of
 hand-subtracting before/after field values.
 
-Tracing is strictly opt-in.  ``obs.enabled`` is the module-level switch
-(default ``False``); hook sites across ``core/scheduler.py``,
-``core/stepper.py``, ``core/engine.py`` and ``kernels/ops.py`` guard on
-it (and on ``obs.tracer``) so the disabled path costs one attribute read
-— no fences, no dict writes, no span objects — and never imports
-``repro.obs.trace`` (the CI guard pins this).  With tracing on, the
-serving lifecycle is recorded as nested spans (query -> wave -> lowering
--> unit step -> kernel dispatch / cache probe / gather-merge /
-overflow-resume) with ``block_until_ready`` fences at span close, and
-exports as JSONL or Chrome trace-event JSON (Perfetto-loadable).
+Spans are strictly opt-in.  ``obs.enabled`` is the module-level switch
+(default ``False``).  Every layer boundary in ``core/scheduler.py``,
+``core/engine.py`` and ``endpoint/service.py`` is one
+``with obs.span(name, view, **args):`` — with the switch off that
+returns a shared no-op (nothing imported, nothing allocated, nothing
+recorded) and never imports ``repro.obs.trace`` (the CI guard pins
+this).  With it on, one span feeds two sinks on one clock:
+
+- a ``jax.profiler.TraceAnnotation(name)``, so the span lands in the
+  profiler's host plane beside the device ops it explains, under exactly
+  ``name`` (visible only while a profiler trace is being taken);
+- an exact scalar ``<prefix>.span_s.<name>`` (summed host seconds) in
+  the component's own registry (``view.registry``, prefix
+  ``view._PREFIX``; the global registry under ``span_s.<name>`` when no
+  view is given), which snapshot diffs turn into per-layer times;
+
+and, when a ``SpanTracer`` is attached (``enable(trace=True)``), a
+Chrome trace-event in memory as well (``obs.trace``).  Spans never
+fence: a span's duration is the host's, and the device's story is the
+profiler trace.  Where the host genuinely waits for the device the
+program says so with a span of its own (``unit.wait``).
 
 The global ``obs.registry`` holds *observability-only* instruments
-(kernel dispatch tallies, engine latency histograms) and is mutated only
-when ``obs.enabled`` — a dedicated test pins zero mutations with the
-switch off.  Functional counters (the ``SchedMetrics`` family) live in
-per-component registries that count regardless, exactly as the old
-dataclass fields did.
+(kernel dispatch tallies, engine latency histograms and span seconds)
+and is mutated only when ``obs.enabled`` — a dedicated test pins zero
+mutations with the switch off.  Functional counters (the
+``SchedMetrics`` family) live in per-component registries that count
+regardless, exactly as the old dataclass fields did.
 """
 
 from __future__ import annotations
 
+import time
 from contextlib import contextmanager
 
 from repro.obs.registry import (  # noqa: F401  (re-exported API)
@@ -43,9 +55,10 @@ from repro.obs.registry import (  # noqa: F401  (re-exported API)
 #: the zero-overhead default the byte-identity suites run under.
 enabled: bool = False
 
-#: The active ``SpanTracer`` or ``None``.  Hook sites bind ``tr =
-#: obs.tracer`` once and emit spans only when it is not ``None`` — the
-#: tracer module is imported lazily so a disabled run never touches it.
+#: The active ``SpanTracer`` or ``None``.  ``span`` records into it, and
+#: the async ``query`` events and instants are emitted only when it is
+#: not ``None`` — the tracer module is imported lazily so a disabled run
+#: never touches it.
 tracer = None
 
 #: Global registry for observability-only instruments (kernel dispatch
@@ -56,8 +69,8 @@ registry = MetricsRegistry()
 
 def enable(trace: bool = True):
     """Turn the instrumentation hooks on; returns the active tracer (or
-    ``None`` when ``trace=False`` — registry-only mode, no spans and no
-    fences)."""
+    ``None`` when ``trace=False`` — registry-only mode: spans still reach
+    the profiler and the registry, just not an in-memory tracer)."""
     global enabled, tracer
     enabled = True
     if trace and tracer is None:
@@ -91,3 +104,76 @@ def tracing(trace: bool = True):
         yield tr
     finally:
         globals()["enabled"], globals()["tracer"] = prev
+
+
+class _NoSpan:
+    """The disabled path's span: one shared instance, does nothing."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+    def note(self, **args) -> None:
+        return None
+
+
+_NO_SPAN = _NoSpan()
+_annotation = None  # jax.profiler.TraceAnnotation, bound on first span
+
+
+class _Span:
+    """A live span: a profiler annotation, the registry's summed seconds
+    and (with a tracer attached) an in-memory event, all from one pair
+    of clock reads."""
+
+    __slots__ = ("name", "reg", "key", "args", "_ann", "_t0")
+
+    def __init__(self, name: str, reg: MetricsRegistry, key: str,
+                 args: dict):
+        self.name = name
+        self.reg = reg
+        self.key = key
+        self.args = args
+
+    def __enter__(self):
+        self._ann = _annotation(self.name)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.perf_counter_ns()
+        self._ann.__exit__(*exc)
+        self.reg.inc(self.key, (t1 - self._t0) * 1e-9)
+        tr = tracer
+        if tr is not None:
+            tr.complete(self.name, self._t0, t1, self.args)
+
+    def note(self, **args) -> None:
+        """Add arguments to the in-memory event (known only at the end)."""
+        self.args.update(args)
+
+
+def span(name: str, view=None, **args):
+    """One layer boundary, as a context manager.
+
+    Off (the default): the shared no-op.  On: a profiler annotation named
+    exactly ``name``, ``name``'s host seconds added to the scalar
+    ``<view._PREFIX>.span_s.<name>`` in ``view.registry`` (a
+    ``RegistryView``; the global registry's ``span_s.<name>`` when
+    ``view`` is ``None``), and a complete event in the attached tracer
+    carrying ``args``.  Nothing blocks on the device."""
+    if not enabled:
+        return _NO_SPAN
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    if view is None:
+        return _Span(name, registry, f"span_s.{name}", args)
+    return _Span(name, view.registry, f"{view._PREFIX}.span_s.{name}", args)

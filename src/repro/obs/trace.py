@@ -1,12 +1,15 @@
-"""Span tracer: the serving lifecycle as a Perfetto-loadable timeline.
+"""Span tracer: the serving lifecycle as an in-memory Chrome timeline.
 
-Records host-side wall spans (``perf_counter_ns``) with optional
-``jax.block_until_ready`` fences at span close — the fence is what makes
-a span's duration mean "this device work finished here" instead of "the
-dispatch returned here", and it exists *only* on this opt-in path: the
-hooks never fence when tracing is off, so the traced and untraced
-executions submit identical device programs (byte-identical results, the
-pinned invariant).
+The in-memory sink of ``obs.span``: each closed span arrives here as a
+complete event with the same ``perf_counter_ns`` readings that its
+registry seconds (``<prefix>.span_s.<name>``) were summed from.  The
+same spans reach the JAX profiler as ``TraceAnnotation``s, on the
+device's clock; this tracer is for tests and for a host-only timeline
+without a profiler.  Nothing here blocks on the device: a span's
+duration is host time (``unit.step`` is the dispatch, ``unit.wait`` the
+host waiting for the step's results), and traced and untraced
+executions submit identical device programs (byte-identical results,
+the pinned invariant).
 
 Event model (Chrome trace-event format):
 
@@ -20,25 +23,13 @@ Event model (Chrome trace-event format):
   point events.
 
 ``export_chrome`` writes the ``{"traceEvents": [...]}`` JSON Perfetto
-and ``chrome://tracing`` load directly; ``export_jsonl`` writes one
-event per line for ad-hoc tooling.
+and ``chrome://tracing`` load directly.
 """
 
 from __future__ import annotations
 
 import json
 import time
-
-
-class Span:
-    """Open-span handle: (name, start ns, args) until ``SpanTracer.end``."""
-
-    __slots__ = ("name", "t0", "args")
-
-    def __init__(self, name: str, t0: int, args: dict):
-        self.name = name
-        self.t0 = t0
-        self.args = args
 
 
 class SpanTracer:
@@ -51,30 +42,16 @@ class SpanTracer:
         return (time.perf_counter_ns() - self._epoch_ns) / 1e3
 
     # -------------------------------------------------------- sync spans
-    def begin(self, name: str, **args) -> Span:
-        return Span(name, time.perf_counter_ns(), args)
-
-    def end(self, span: Span, fence=None, **args) -> None:
-        """Close a span; ``fence`` (any pytree of jax arrays) is
-        block_until_ready'd first so the span covers the device work it
-        wraps, not just the dispatch."""
-        if fence is not None:
-            import jax
-
-            jax.block_until_ready(fence)
-        t1 = time.perf_counter_ns()
-        if args:
-            span.args.update(args)
+    def complete(self, name: str, t0_ns: int, t1_ns: int, args: dict
+                 ) -> None:
+        """Record a closed ``obs.span``: ``perf_counter_ns`` readings at
+        its open and close."""
         self.events.append({
-            "name": span.name, "ph": "X", "pid": 0, "tid": 0,
-            "ts": (span.t0 - self._epoch_ns) / 1e3,
-            "dur": (t1 - span.t0) / 1e3,
-            "args": span.args,
+            "name": name, "ph": "X", "pid": 0, "tid": 0,
+            "ts": (t0_ns - self._epoch_ns) / 1e3,
+            "dur": (t1_ns - t0_ns) / 1e3,
+            "args": args,
         })
-
-    def span(self, name: str, fence=None, **args):
-        """Context-manager form of begin/end (same fence semantics)."""
-        return _SpanCtx(self, name, fence, args)
 
     # ------------------------------------------------------ async spans
     def begin_async(self, name: str, aid, **args) -> None:
@@ -113,25 +90,3 @@ class SpanTracer:
     def export_chrome(self, path: str) -> None:
         with open(path, "w") as f:
             json.dump(self.to_chrome(), f)
-
-    def export_jsonl(self, path: str) -> None:
-        with open(path, "w") as f:
-            for e in self.events:
-                f.write(json.dumps(e) + "\n")
-
-
-class _SpanCtx:
-    __slots__ = ("tracer", "name", "fence", "args", "_span")
-
-    def __init__(self, tracer: SpanTracer, name: str, fence, args: dict):
-        self.tracer = tracer
-        self.name = name
-        self.fence = fence
-        self.args = args
-
-    def __enter__(self) -> Span:
-        self._span = self.tracer.begin(self.name, **self.args)
-        return self._span
-
-    def __exit__(self, *exc) -> None:
-        self.tracer.end(self._span, fence=self.fence)

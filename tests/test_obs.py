@@ -196,7 +196,7 @@ def test_disabled_by_default_and_lazy_tracer_import():
 
 def test_disabled_zero_registry_mutation_and_byte_identity(watdiv_small):
     """The pinned tentpole invariant: with obs off (default), serving
-    mutates NO global-registry instrument, and enabling tracing (fences
+    mutates NO global-registry instrument, and enabling tracing (spans
     and all) changes no result byte and no gross stat."""
     g, store = watdiv_small
     qs = generate_query_load(g, store, "union", QueryLoadConfig(n_queries=3))
@@ -424,10 +424,180 @@ def test_traced_64_client_union_load_perfetto(watdiv_small, tmp_path):
     disp = {k: v for k, v in obs.registry.snapshot().items()
             if k.startswith("kernels.dispatch.")}
     assert sum(disp.values()) >= len(kernel_instants) > 0
+    obs.registry.reset()
 
-    # jsonl export round-trips the same events
-    jl = tmp_path / "trace.jsonl"
-    tracer.export_jsonl(str(jl))
-    lines = [json.loads(s) for s in jl.read_text().splitlines()]
-    assert lines == events
+
+# --------------------------------------------------------------------------
+# program spans: one API, two sinks, no fences
+# --------------------------------------------------------------------------
+
+def test_span_is_a_shared_noop_when_disabled():
+    """Off, ``obs.span`` hands out one shared object that records
+    nothing: no registry instrument, no tracer, whatever its args."""
+    obs.registry.reset()
+    assert not obs.enabled
+    a = obs.span("wave")
+    b = obs.span("unit.pull", None, k=3)
+    assert a is b
+    with a as sp:
+        sp.note(hits=1)
+    assert len(obs.registry) == 0
+
+
+def _host_events(tdir):
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(tdir, "**", "*.xplane.pb"),
+                        recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                out += [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                        for ev in line.events]
+    return out
+
+
+def test_profiler_host_plane_carries_program_spans(watdiv_small, tmp_path):
+    """Registry-only mode (``trace=False``, as a traced benchmark window
+    runs) puts the scheduler's spans in the profiler's host plane under
+    exactly their names, each inside a ``wave`` event."""
+    import jax
+
+    g, store = watdiv_small
+    qs = generate_query_load(g, store, "union", QueryLoadConfig(n_queries=2))
+    cfg = EngineConfig(interface="spf", cap=2048)
+    stream = interleave_clients(list(qs), 4)
+    QueryScheduler(store, cfg, SchedulerConfig(lanes=8)).serve(stream)
+    sched = QueryScheduler(store, cfg, SchedulerConfig(lanes=8))  # cold
+    with obs.tracing(trace=False) as tracer:
+        assert tracer is None
+        with jax.profiler.trace(str(tmp_path)):
+            sched.serve(stream)
+    events = _host_events(tmp_path)
+    waves = [(s, e) for n, s, e in events if n == "wave"]
+    assert len(waves) == sched.metrics.waves
+    for name in ("unit.wait", "unit.pull", "cache.insert", "wave.finalize",
+                 "cache.probe"):
+        spans = [(s, e) for n, s, e in events if n == name]
+        assert spans, name
+        assert all(any(ws <= s and e <= we for ws, we in waves)
+                   for s, e in spans), name
+    obs.registry.reset()
+
+
+def test_host_syncs_per_unit_step(watdiv_small):
+    """``sched.host_syncs`` counts every blocking pull: per dispatched
+    step the digest and four stat arrays, two per inserted miss, one per
+    overflow checkpoint, one digest per replayed unit, and finalize's two
+    per wave.  An all-hit pass adds no insertion pulls: only its digests
+    and finalizes."""
+    g, store = watdiv_small
+    qs = generate_query_load(g, store, "union", QueryLoadConfig(n_queries=3))
+    cfg = EngineConfig(interface="spf", cap=2048)
+    sched = QueryScheduler(store, cfg,
+                           SchedulerConfig(lanes=8, cap_hints=False))
+    with obs.tracing() as tracer:
+        sched.run_queries(qs)
+    m = sched.metrics
+    inserted = tracer.count("cache.insert", "X")
+    assert m.steps > 0 and inserted > 0
+    assert m.host_block_pulls == inserted + m.retries
+    assert m.host_syncs == (5 * m.steps + 2 * inserted + m.retries
+                            + m.steps_skipped + 2 * m.waves)
+
+    base = sched.snapshot()
+    _, stats = sched.run_queries(qs)  # obs off: counted all the same
+    d = sched.snapshot() - base
+    assert all(int(s.cache_misses) == 0 for s in stats)
+    assert d.scalar("sched.steps") == 0
+    assert d.scalar("sched.host_block_pulls") == 0
+    assert d.scalar("sched.host_syncs") == \
+        d.scalar("sched.steps_skipped") + 2 * d.scalar("sched.waves")
+    obs.registry.reset()
+
+
+def _span_sums(tracer) -> dict:
+    sums: dict = {}
+    for e in tracer.events:
+        if e["ph"] == "X":
+            sums[e["name"]] = sums.get(e["name"], 0.0) + e["dur"] * 1e-6
+    return sums
+
+
+def _span_s(snap, prefix: str) -> dict:
+    head = f"{prefix}.span_s."
+    return {k[len(head):]: v for k, v in snap.items() if k.startswith(head)}
+
+
+def test_span_seconds_equal_tracer_events(watdiv_small):
+    """With a tracer attached, each ``sched.span_s.<name>`` is exactly
+    the summed duration of the in-memory ``<name>`` events; waves and
+    the queries they answer share the wave's number.  Off, no
+    ``span_s`` instrument exists anywhere."""
+    g, store = watdiv_small
+    qs = generate_query_load(g, store, "2-stars",
+                             QueryLoadConfig(n_queries=3))
+    cfg = EngineConfig(interface="spf", cap=2048)
+    stream = interleave_clients(list(qs), 3)
+    sched = QueryScheduler(store, cfg, SchedulerConfig(lanes=8))
+    with obs.tracing() as tracer:
+        sched.serve(stream)
+    sums = _span_sums(tracer)
+    got = _span_s(sched.snapshot(), "sched")
+    assert set(got) == set(sums)
+    for name in ("sched.drain", "sched.plan", "wave", "unit", "cache.probe",
+                 "wave.lower", "unit.step", "unit.wait", "unit.pull",
+                 "cache.insert", "unit.account", "wave.finalize"):
+        assert got[name] == pytest.approx(sums[name], rel=1e-9), name
+    waves = {e["args"]["wave"] for e in tracer.named("wave")}
+    assert waves == set(range(1, sched.metrics.waves + 1))
+    ends = [e for e in tracer.named("query") if e["ph"] == "e"]
+    assert len(ends) == len(stream)
+    assert {e["args"]["wave"] for e in ends} <= waves
+
+    obs.registry.reset()
+    off = QueryScheduler(store, cfg, SchedulerConfig(lanes=8))
+    off.serve(stream)
+    assert not [k for k in off.snapshot() if ".span_s." in k]
+    assert len(obs.registry) == 0
+
+
+def test_endpoint_spans_on_the_scheduler_registry():
+    """The front door's spans (``endpoint.parse``, ``endpoint.batch``,
+    ``endpoint.deliver``) sum into ``endpoint.span_s.*`` on the
+    scheduler's registry, beside the scheduler's own, and deliver runs
+    inside its batch."""
+    from repro.core.patterns import BGP, C, TriplePattern, V
+    from repro.endpoint import to_sparql
+    from repro.endpoint.service import EndpointRequest, EndpointService
+    from repro.rdf import TripleStore
+
+    store = TripleStore.build(np.array([0, 0, 1, 1, 2, 2, 3, 3]),
+                              np.array([0, 1, 0, 1, 0, 1, 0, 1]),
+                              np.array([3, 4, 3, 5, 3, 4, 4, 5]),
+                              n_terms=6, n_predicates=2)
+    bgp = BGP((TriplePattern(V(0), C(0), V(1)),
+               TriplePattern(V(0), C(1), V(2))), 3)
+    sched = QueryScheduler(store, EngineConfig(interface="spf"))
+    svc = EndpointService(sched)
+    with obs.tracing() as tracer:
+        resps = svc.serve([EndpointRequest(client=i % 3,
+                                           sparql=to_sparql(bgp))
+                           for i in range(6)])
+    assert all(r.status == "ok" for r in resps)
+    sums = _span_sums(tracer)
+    snap = sched.snapshot()
+    front = _span_s(snap, "endpoint")
+    assert {"endpoint.parse", "endpoint.batch", "endpoint.deliver"} \
+        <= set(front)
+    for name, v in front.items():
+        assert v == pytest.approx(sums[name], rel=1e-9), name
+    assert "wave" in _span_s(snap, "sched")
+    batches = tracer.named("endpoint.batch")
+    for d in tracer.named("endpoint.deliver"):
+        assert any(b["ts"] <= d["ts"] and d["ts"] + d["dur"]
+                   <= b["ts"] + b["dur"] + 1e-3 for b in batches)
     obs.registry.reset()
