@@ -62,8 +62,12 @@ clients and turns it into device-efficient work:
    fragment deltas — the small objects — are uploaded and scattered onto
    the lanes' seed prefixes in place, so an all-hit wave performs **zero**
    host Omega materialisations (``SchedMetrics.host_block_pulls`` counts
-   the exceptions: a miss pulls just that lane's output prefix to record
-   the replayable delta, an overflow-retire pulls its checkpoint seed).
+   the exceptions: a miss records that lane's output prefix as the
+   replayable delta, an overflow-retire pulls its checkpoint seed).  A
+   dispatched step reaches the host in one transfer: its stats and, when
+   a lane missed and the wave is within ``WAVE_PULL_BYTES``, the whole
+   wave's output rows, from which each miss's prefix is sliced on the
+   host.
    The digest is a pure function of the valid prefix, which is
    byte-identical across all three lowerings and every shard count, so
    fragments recorded under one lowering serve waves under any other.
@@ -117,6 +121,21 @@ from repro.core.patterns import BGP
 from repro.core.server import log_factor, unit_digest_key, unit_io
 from repro.kernels import ops as kops
 from repro.rdf.store import TripleStore
+
+
+# The largest unit-step output, rows and provenance (``B * cap * (V + 1)``
+# int32 words, in bytes), that comes to the host whole, in the same
+# transfer as the step's stats, when a lane missed the cache.  Past it a
+# missed lane's prefix is pulled on its own, two device slices and two
+# round trips a lane (``QueryScheduler._insert``).  Measured on one TPU
+# v5 lite (``benchmarks/pull_transfer.py``): one lane's own pull pair
+# takes 3.0-3.4 ms at every wave size; the whole wave with its stats in
+# one ``device_get`` 0.70 ms at 0.33 MB, 1.34 ms at 5.2 MB, 2.78 ms at
+# 21 MB and 3.57 ms at 31 MB, then 17.5 ms at 42 MB and 31.7 ms at 84 MB.
+# Up to 20 MiB (8 lanes x 65,536 rows x 10 words) the one transfer costs
+# less than a single missed lane's pulls; at 42 MB it costs more than
+# five lanes' pulls, at 84 MB more than all eight.
+WAVE_PULL_BYTES = 20 << 20
 
 
 @dataclass(frozen=True)
@@ -240,18 +259,23 @@ class SchedMetrics(obs.RegistryView):
         # check): answered (None, stats-so-far) — the endpoint maps the
         # None table to a "timeout" response
         "deadline_expired",
-        # Omega-block device->host pulls during unit stepping
-        # (miss-insertion prefix pulls + overflow-retire checkpoints;
-        # finalize excluded).  The device-replay invariant the tests pin:
-        # an all-hit wave adds zero.
+        # Omega blocks materialised on the host during unit stepping, one
+        # per lane: each recorded miss's prefix (sliced from the step's
+        # one transfer, or pulled on its own past the byte budget) and
+        # each overflow-retire checkpoint; finalize excluded.  The
+        # device-replay invariant the tests pin: an all-hit wave adds zero.
         "host_block_pulls",
-        # every blocking device->host transfer of a wave (each np.asarray
-        # of a device array: per unit the digest, the step's four stat
-        # arrays and two per inserted miss; the sharded occupancy pull,
-        # overflow checkpoints, and finalize's two).  A host round trip
-        # each, whatever the payload: what bounds the host's share of a
-        # unit step
+        # every blocking device->host call of a wave, however many arrays
+        # it carries (per unit the digest and the step's one transfer,
+        # two per recorded miss past the byte budget, one per overflow
+        # checkpoint, and finalize's two).  A host round trip each,
+        # whatever the payload: what bounds the host's share of a unit
+        # step
         "host_syncs",
+        # dispatched steps whose output rows came to the host whole, in
+        # the step's one transfer, for the cache to record its misses
+        # (the share of steps with a miss is the engagement rate)
+        "wave_pulls",
         # bytes moved by the sharded lowering's per-unit gather
         # collectives (benchlib folds these into the modeled throughput
         # so sharded BENCH numbers are not silently optimistic)
@@ -707,10 +731,15 @@ class QueryScheduler:
         (uploaded deltas), and Omega blocks cross to the host only for a
         miss's recorded prefix or an overflow-retire's checkpoint
         (counted in ``metrics.host_block_pulls``) — and once at finalize
-        to deliver the responses.  Every blocking pull, the small ones
-        included, counts in ``metrics.host_syncs``; each host phase is an
-        ``obs.span`` (``cache.probe``, ``unit.step`` for the dispatch,
-        ``unit.wait`` for the device time, ``unit.pull``,
+        to deliver the responses.  A dispatched step's outputs come to
+        the host in one ``jax.device_get``: its stats and, when a lane
+        missed and ``B * cap * (V + 1)`` int32 fit ``WAVE_PULL_BYTES``,
+        the whole wave's rows and provenance (``metrics.wave_pulls``);
+        past the budget each missed lane pulls its own prefix.  Every
+        blocking call, the small ones included, counts in
+        ``metrics.host_syncs``; each host phase is an ``obs.span``
+        (``cache.probe``, ``unit.step`` for the dispatch, ``unit.wait``
+        for the device time, ``unit.pull`` for the transfer,
         ``cache.insert``, ``unit.account``, ``wave.finalize``).
         """
         scfg = self.scfg
@@ -867,12 +896,34 @@ class QueryScheduler:
                         # trims; the vmap/replicated steps return the
                         # common 7
                         r_o, v_o, o_o, src_o, ops_o, cnt_o, peak_o = out[:7]
+                        # the missed lanes the cache records; none on a
+                        # stale-pinned retry wave, whose fragments describe
+                        # a superseded epoch and would only park dead
+                        # weight under an old-epoch key
+                        record = {j for j in active
+                                  if status[j][0] == "miss"
+                                  and not bool(ovf[j])} \
+                            if scfg.use_cache \
+                            and epoch == self.store.epoch else set()
+                        # one transfer a step: the stats, and the whole
+                        # wave's rows when a lane is recorded and they fit
+                        # the byte budget; the misses are sliced from them
+                        # on the host
+                        whole = bool(record) and \
+                            B * cap * (V + 1) * 4 <= WAVE_PULL_BYTES
+                        occupancy = use_shard and not latch
+                        pull = (ops_o, cnt_o, o_o, peak_o) \
+                            + ((out[7],) if occupancy else ()) \
+                            + ((r_o, src_o) if whole else ())
                         with obs.span("unit.pull", self.metrics):
-                            ops_np = np.asarray(ops_o)
-                            cnt_np = np.asarray(cnt_o)
-                            ovf_np = np.asarray(o_o)
-                            peak_np = np.asarray(peak_o)
-                            self.metrics.host_syncs += 4
+                            host = jax.device_get(pull)
+                            self.metrics.host_syncs += 1
+                        ops_np, cnt_np, ovf_np, peak_np = host[:4]
+                        if whole:
+                            self.metrics.wave_pulls += 1
+                            lane_rows = host[-2:]
+                        else:
+                            lane_rows = (r_o, src_o)
                         self.metrics.steps += 1
                         self.metrics.lane_steps += B
                         self.metrics.active_lane_steps += len(active)
@@ -886,14 +937,8 @@ class QueryScheduler:
                                 # are never re-run
                                 _retire(j, k)
                                 continue
-                            if status[j][0] == "miss" and scfg.use_cache \
-                                    and not bool(ovf[j]) \
-                                    and epoch == self.store.epoch:
-                                # (a stale-pinned retry wave skips
-                                # insertion: its fragments describe a
-                                # superseded epoch and would only park dead
-                                # weight under an old-epoch key)
-                                self._insert(keys[j], io, j, r_o, src_o,
+                            if j in record:
+                                self._insert(keys[j], io, j, *lane_rows,
                                              int(cnt_np[j]),
                                              bool(ovf_np[j]),
                                              int(ops_np[j]),
@@ -906,7 +951,7 @@ class QueryScheduler:
                                 jobs[j].peak_seen = max(jobs[j].peak_seen,
                                                         int(peak_np[j]),
                                                         n_in[j])
-                        if use_shard and not latch:
+                        if occupancy:
                             # feed the measured per-shard occupancy back
                             # into the planner so the next wave of this
                             # unit trims its merge to what shards actually
@@ -915,14 +960,11 @@ class QueryScheduler:
                             # count, not a per-shard block), and retired
                             # lanes are skipped because a clamped table's
                             # peak understates the true need
-                            with obs.span("unit.pull", self.metrics):
-                                sp = np.asarray(out[7])
-                                self.metrics.host_syncs += 1
                             for j in active:
                                 if j not in retired:
                                     self.planner.observe_shard_peak(
                                         jobs[j].plan, k, self._n_shards,
-                                        int(sp[j]))
+                                        int(host[4][j]))
                     else:
                         # every active lane hit: replay the cached deltas
                         # on the device (stepper.replay_step /
@@ -1062,19 +1104,28 @@ class QueryScheduler:
                                      self._lane_axes, logn=view.logn)
         return stepper.unit_step(up, self.store.radix, logn=view.logn)
 
-    def _insert(self, key: tuple, io, j: int, r_o, src_o, n_out: int,
+    def _insert(self, key: tuple, io, j: int, rows, src, n_out: int,
                 overflow: bool, ops: int, peak: int, epoch: int) -> None:
-        """Record a missed lane's replayable delta: pull only this lane's
-        output prefix and provenance (one counted Omega-block pull, two
-        host syncs) and put the fragment in the cache."""
+        """Record a missed lane's replayable delta (one counted Omega
+        block) and put the fragment in the cache.  ``rows`` and ``src``
+        are the wave's output rows and provenance: host arrays from the
+        step's one transfer, or, past ``WAVE_PULL_BYTES``, device arrays
+        of which only this lane's prefix is pulled (two host syncs)."""
         with obs.span("cache.insert", self.metrics):
             self.metrics.host_block_pulls += 1
-            self.metrics.host_syncs += 2
-            out_rows = np.asarray(r_o[j, :n_out])
+            if isinstance(rows, np.ndarray):
+                out_rows, src_row = rows[j, :n_out], src[j, :n_out]
+            else:
+                self.metrics.host_syncs += 2
+                out_rows = np.asarray(rows[j, :n_out])
+                src_row = np.asarray(src[j, :n_out])
+            # copies: an entry owns its bytes, where a view of the wave's
+            # host arrays would keep the whole wave alive (and
+            # ``bytes_stored`` would understate it)
             entry = FragmentEntry(
-                src_row=np.ascontiguousarray(np.asarray(src_o[j, :n_out])),
-                written=np.ascontiguousarray(
-                    out_rows[:, list(io.write_cols)]),
+                src_row=np.array(src_row, order="C"),
+                written=np.array(out_rows[:, list(io.write_cols)],
+                                 order="C"),
                 overflow=overflow,
                 ops=ops,
                 epoch=epoch,

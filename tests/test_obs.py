@@ -489,11 +489,13 @@ def test_profiler_host_plane_carries_program_spans(watdiv_small, tmp_path):
 
 
 def test_host_syncs_per_unit_step(watdiv_small):
-    """``sched.host_syncs`` counts every blocking pull: per dispatched
-    step the digest and four stat arrays, two per inserted miss, one per
-    overflow checkpoint, one digest per replayed unit, and finalize's two
-    per wave.  An all-hit pass adds no insertion pulls: only its digests
-    and finalizes."""
+    """``sched.host_syncs`` counts every blocking call: per dispatched
+    step the digest and the step's one transfer (its stats and, under the
+    byte budget, the whole wave's rows the misses are recorded from), one
+    per overflow checkpoint, one digest per replayed unit, and finalize's
+    two per wave.  Every dispatched step had a miss and pulled its wave.
+    An all-hit pass adds no transfers of a step: only its digests and
+    finalizes."""
     g, store = watdiv_small
     qs = generate_query_load(g, store, "union", QueryLoadConfig(n_queries=3))
     cfg = EngineConfig(interface="spf", cap=2048)
@@ -505,7 +507,8 @@ def test_host_syncs_per_unit_step(watdiv_small):
     inserted = tracer.count("cache.insert", "X")
     assert m.steps > 0 and inserted > 0
     assert m.host_block_pulls == inserted + m.retries
-    assert m.host_syncs == (5 * m.steps + 2 * inserted + m.retries
+    assert m.wave_pulls == m.steps
+    assert m.host_syncs == (2 * m.steps + m.retries
                             + m.steps_skipped + 2 * m.waves)
 
     base = sched.snapshot()

@@ -651,11 +651,103 @@ def test_all_hit_wave_zero_host_materializations(watdiv_small, all_queries):
     assert sched.metrics.steps == steps0, "all-hit pass dispatched steps"
     assert sched.metrics.host_block_pulls == pulls0, \
         "all-hit pass materialised Omega blocks on the host"
+    assert sched.metrics.wave_pulls == steps0, "all-hit pass pulled a wave"
     assert sched.metrics.steps_skipped > skipped0
     assert all(int(s.cache_misses) == 0 and int(s.cache_hits) > 0
                for s in stats)
     for a, b in zip(first_tables, tables):
         assert np.array_equal(results_as_numpy(a), results_as_numpy(b))
+
+
+# --------------------------------------------------------------------------
+# a unit step's outputs reach the host in one transfer (WAVE_PULL_BYTES)
+# --------------------------------------------------------------------------
+
+LOWERINGS = ["vmap", "mesh", "shard"]
+# either side of the scheduler's byte budget: every wave pulled whole, or
+# every missed lane pulled on its own
+BUDGETS = {"wave_pull": 1 << 40, "lane_pulls": 0}
+
+
+@pytest.fixture(scope="module")
+def budget_serves(watdiv_small, all_queries):
+    """Serve one interleaved load per (lowering, side of the byte budget),
+    with the module constant patched for the serve; memoised."""
+    from repro.core import scheduler as sched_mod
+
+    _, store = watdiv_small
+    qs = all_queries[:6]
+    runs = {}
+
+    def serve(lowering: str, side: str):
+        if (lowering, side) not in runs:
+            mesh, kw = None, {}
+            if lowering == "mesh":
+                mesh = _device_mesh()
+            elif lowering == "shard":
+                mesh, kw = _shard_meshes()[0][2], {"data_axis": "data"}
+            with pytest.MonkeyPatch.context() as mp:
+                mp.setattr(sched_mod, "WAVE_PULL_BYTES", BUDGETS[side])
+                sched = QueryScheduler(
+                    store, EngineConfig(interface="spf", cap=2048),
+                    SchedulerConfig(lanes=8, cap_hints=False,
+                                    collapse_duplicates=False),
+                    mesh=mesh, **kw)
+                served = sched.serve(interleave_clients(qs, 2))
+            runs[lowering, side] = (sched, [t for t, _ in served])
+        return runs[lowering, side]
+
+    return serve
+
+
+@pytest.mark.parametrize("lowering", LOWERINGS)
+def test_wave_pull_records_what_lane_pulls_record(budget_serves, lowering):
+    """Under the byte budget a step's rows come to the host whole, in its
+    one transfer, and each miss is sliced from them; past it each missed
+    lane pulls its own prefix.  Both record byte-identical fragments (same
+    keys, LRU order, arrays, dtypes and fields) and serve identical rows;
+    the per-lane side costs two more host syncs per recorded lane."""
+    whole, t_whole = budget_serves(lowering, "wave_pull")
+    lanes, t_lanes = budget_serves(lowering, "lane_pulls")
+    wm, lm = whole.metrics, lanes.metrics
+    assert wm.steps == lm.steps > 0
+    if lowering == "shard":
+        assert wm.shard_steps == wm.steps
+    elif lowering == "mesh":
+        assert wm.mesh_steps == wm.steps
+    assert wm.wave_pulls == wm.steps and lm.wave_pulls == 0
+    assert wm.host_block_pulls == lm.host_block_pulls > wm.retries
+    recorded = wm.host_block_pulls - wm.retries
+    assert lm.host_syncs - wm.host_syncs == 2 * recorded
+    for a, b in zip(t_whole, t_lanes, strict=True):
+        assert np.array_equal(results_as_numpy(a), results_as_numpy(b))
+    (pos_w, neg_w), (pos_l, neg_l) = (whole.cache.export_state(),
+                                      lanes.cache.export_state())
+    assert pos_w and neg_w == neg_l
+    assert [k for k, _ in pos_w] == [k for k, _ in pos_l]
+    for (_, ew), (_, el) in zip(pos_w, pos_l):
+        for f in ("src_row", "written"):
+            x, y = getattr(ew, f), getattr(el, f)
+            assert x.dtype == y.dtype and x.shape == y.shape, f
+            assert x.tobytes() == y.tobytes(), f
+        assert ew._replace(src_row=None, written=None) \
+            == el._replace(src_row=None, written=None)
+
+
+@pytest.mark.parametrize("side", list(BUDGETS))
+def test_cached_fragments_own_their_bytes(budget_serves, side):
+    """Every stored fragment owns its memory (a copy, never a view that
+    would keep a whole wave alive on the host), so ``bytes_stored`` is
+    the sum of the entries' own bytes."""
+    sched, _ = budget_serves("vmap", side)
+    entries, _ = sched.cache.export_state()
+    assert entries
+    for _, e in entries:
+        for a in (e.src_row, e.written):
+            assert a.flags.owndata and a.flags.c_contiguous
+            assert a.base is None
+    assert sched.cache.stats.bytes_stored == sum(e.nbytes
+                                                 for _, e in entries)
 
 
 def test_mixed_signature_distributed_batch(watdiv_small):
