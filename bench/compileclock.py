@@ -5,7 +5,8 @@ Copied from the bring-up smoke's ``CompileClock`` so that the benchmark
 owns it.  ``backend_compiles`` counts the programs JAX compiled or loaded from
 the persistent cache (it reports a ``backend_compile_duration`` for
 both); ``cache_hits`` and ``cache_misses`` split them where the
-persistent cache is on.
+persistent cache is on.  ``programs`` names each program in the order
+it came, so a run can say which ones compiled where none should.
 """
 
 from __future__ import annotations
@@ -21,12 +22,14 @@ class CompileClock:
         self.secs = 0.0
         self.backend_compiles = 0
         self.hits = self.misses = 0
+        self.programs: list[str] = []
 
-        def on_duration(event, duration, **_):
+        def on_duration(event, duration, fun_name="?", **_):
             if event == "/jax/core/compile/backend_compile_duration":
                 with self._lock:
                     self.secs += duration
                     self.backend_compiles += 1
+                    self.programs.append(str(fun_name))
 
         def on_event(event, **_):
             with self._lock:

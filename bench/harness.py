@@ -146,6 +146,7 @@ class Marks:
     def __init__(self, svc, clock, trace: bool):
         self.svc, self.clock, self.trace = svc, clock, trace
         self.snap = self.compiles = None
+        self.programs: list[str] = []  # names of those compiled inside
         self._ann = None
 
     def _snapshot(self):
@@ -159,6 +160,7 @@ class Marks:
 
     def open(self) -> float:
         self._before, self._c0 = self._snapshot(), self.clock.read()
+        self._n0 = len(self.clock.programs)
         if self.trace:
             import jax
 
@@ -173,6 +175,7 @@ class Marks:
         self.snap = self._snapshot() - self._before
         self.compiles = {k: v - self._c0[k]
                          for k, v in self.clock.read().items()}
+        self.programs = self.clock.programs[self._n0:]
 
 
 async def _closed_loop(svc, marks: Marks, stream, clients: int,
@@ -195,6 +198,8 @@ async def _closed_loop(svc, marks: Marks, stream, clients: int,
                 inst = next(stream)
             except tf.PoolExhausted as e:
                 exhausted.append(str(e))
+                return
+            except StopIteration:  # another client's next() exhausted it
                 return
             rec = Record(inst, sent=time.perf_counter())
             records.append(rec)
@@ -245,7 +250,8 @@ def build(cell: Cell, seed: int, scale: int | None, log):
     k = len(cell.mix["templates"])
     insts = tf.Instances(g, cell.mix["templates"],
                          int(cell.config["fit_rows"]), seed,
-                         reserve=-(-int(cell.mix["clients"]) // k))
+                         reserve=-(-int(cell.mix["clients"]) // k),
+                         cap=int(cell.config["engine"]["cap"]))
     log(phase="instances", distinct=[int(r.shape[0]) for r in insts.roots],
         pool=insts.pool(), cut_share=insts.cut_share())
     ecfg = EngineConfig(**cell.config["engine"])
@@ -262,58 +268,30 @@ def warm_up(cell: Cell, service, insts: tf.Instances, log):
     """Serve one drain shaped like the window's (``clients`` requests,
     the templates in turn, so each template's requests fill the same
     waves) of instances no window sends, so that the programs the window
-    runs are compiled here, then the scheduler's pulls of every row count
-    the cut allows.  Returns the warm service, whose planner the window's
-    service takes over."""
-    from repro.core.scheduler import SchedulerConfig
+    runs are compiled here; then, in a drain of their own, the instances
+    whose bound passes the engine's ``cap`` (``tf.Instances.past_cap``),
+    so that the larger capacities they need compile here too.  Returns
+    the warm service, whose planner the window's service takes over."""
     from repro.endpoint.service import EndpointRequest
 
     svc = service()
     pools = insts.warmup()
     clients = int(cell.mix["clients"])
-    reqs = [EndpointRequest(client=i, sparql=pools[i % len(pools)][
+    drains = [[EndpointRequest(client=i, sparql=pools[i % len(pools)][
         (i // len(pools)) % len(pools[i % len(pools)])].text)
-        for i in range(clients)]
+        for i in range(clients)]]
+    big = insts.past_cap()
+    if big:
+        drains.append([EndpointRequest(client=i, sparql=inst.text)
+                       for i, inst in enumerate(big)])
     t = time.perf_counter()
-    bad = [x.status for x in svc.serve(reqs) if x.status != "ok"]
-    if bad:
-        raise BenchError(f"warm-up answers not ok: {bad[:3]}")
-    log(phase="warmup", requests=clients, wall_s=time.perf_counter() - t)
-    # the window's waves: each template's share of a drain, as the
-    # scheduler pads it (a power of two, at most its lanes)
-    lanes = SchedulerConfig(**cell.config["scheduler"]).lanes
-    per = -(-clients // len(pools))
-    width = min(lanes, 1 << (per - 1).bit_length())
-    t = time.perf_counter()
-    n = prewarm_pulls(width, int(cell.config["engine"]["cap"]),
-                      sorted({i.n_vars for pool in pools for i in pool}),
-                      int(cell.config["fit_rows"]))
-    log(phase="warmup_pulls", pulls=n, wall_s=time.perf_counter() - t)
+    for reqs in drains:
+        bad = [x.status for x in svc.serve(reqs) if x.status != "ok"]
+        if bad:
+            raise BenchError(f"warm-up answers not ok: {bad[:3]}")
+    log(phase="warmup", requests=clients, past_cap=len(big),
+        wall_s=time.perf_counter() - t)
     return svc
-
-
-def prewarm_pulls(width: int, cap: int, n_vars: list[int],
-                  max_rows: int) -> int:
-    """Compile the scheduler's host pulls of a lane's output prefix for
-    every row count the traffic can produce.
-
-    On a fragment-cache miss the scheduler copies lane ``j``'s first
-    ``n`` output rows and their provenance to the host (``rows[j, :n]``,
-    ``src[j, :n]`` on the wave's ``[B, cap, V]`` / ``[B, cap]`` arrays).
-    Each new ``n`` compiles a slice and a squeeze program (0.15-0.7 s
-    each on the chip's host), which a long-running server has compiled
-    long ago; the same indexing here, on arrays of those shapes, puts
-    them in JAX's caches before the window.  One at a time: compiles in
-    parallel threads contend and take longer in all.  Returns how many
-    pulls were warmed."""
-    import jax.numpy as jnp
-
-    arrays = [jnp.zeros((width, cap, v), jnp.int32) for v in n_vars] \
-        + [jnp.zeros((width, cap), jnp.int32)]
-    for a in arrays:
-        for n in range(max_rows + 1):
-            a[0, :n].block_until_ready()
-    return len(arrays) * (max_rows + 1)
 
 
 # ------------------------------------------------------------------- metrics
@@ -431,6 +409,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         out["breakdown"] = w.trace["breakdown"]
     out["compared"] = compared
     log(phase="done", compiles_in_window=w.compiles,
+        compiled_in_window=marks.programs,
         ok=len(w.ok()), due=len(due),
         timeline=[[round(r.sent - w.t0, 4), round(r.done - w.t0, 4),
                    r.status] for r in w.records])

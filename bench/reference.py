@@ -3,8 +3,9 @@
 It reads only the triples the benchmark generated (``watdiv.Graph``) and
 imports nothing of the program.  Evaluation is a left-deep join, one
 pattern at a time over two sorted copies of the triples, by ``(p, s)`` and
-by ``(p, o)``: a constant-object pattern first, then the others in the
-order given, each joined on a variable already bound.  Answers are
+by ``(p, o)``: a constant-object pattern first (else a constant-subject
+one), then the others in the order given, each joined on a variable
+already bound or looked up by its constant subject.  Answers are
 compared as sets of rows in a canonical (lexicographic) order, so the
 reference need not reproduce the server's row order.
 
@@ -51,9 +52,10 @@ class Reference:
             o_var = o_t[1] if isinstance(o_t, tuple) else None
             if cols is None:
                 cols = self._first(s_t, p, o_t, s_var, o_var)
-                continue
-            if s_var is not None and s_var in cols:
-                subj = cols[s_var]
+            elif s_var is None or s_var in cols:
+                n = next(iter(cols.values())).shape[0]
+                subj = cols[s_var] if s_var is not None \
+                    else np.full(n, s_t, np.int64)
                 lo, hi = self._runs(self.ps_key, p * self.r + subj)
                 cols, objs = self._expand(cols, lo, hi, self.ps_o)
                 if o_var is None:
@@ -61,17 +63,14 @@ class Reference:
                 elif o_var in cols:
                     keep = objs == cols[o_var]
                 else:
+                    keep = None
                     cols[o_var] = objs
-                    continue
-                cols = {k: v[keep] for k, v in cols.items()}
+                if keep is not None:
+                    cols = {k: v[keep] for k, v in cols.items()}
             elif o_var is not None and o_var in cols:
                 lo, hi = self._runs(self.po_key, p * self.r + cols[o_var])
                 cols, subs = self._expand(cols, lo, hi, self.po_s)
-                if s_var is None:
-                    keep = subs == s_t
-                    cols = {k: v[keep] for k, v in cols.items()}
-                else:
-                    cols[s_var] = subs
+                cols[s_var] = subs
             else:
                 raise ValueError("patterns must join on a bound variable")
         out = np.stack([cols[i] for i in range(n_vars)], axis=1) \
@@ -85,27 +84,32 @@ class Reference:
             if s_var is None:
                 raise ValueError("a fully constant pattern is not a query")
             return {s_var: subs.copy()}
+        if s_var is None:
+            lo, hi = self._runs(self.ps_key, np.array([p * self.r + s_t]))
+            return {o_var: self.ps_o[lo[0]:hi[0]].copy()}
         lo = np.searchsorted(self.ps_key, p * self.r, "left")
         hi = np.searchsorted(self.ps_key, (p + 1) * self.r, "left")
         subs = self.ps_key[lo:hi] - p * self.r
         cols = {o_var: self.ps_o[lo:hi].copy()}
-        if s_var is None:
-            keep = subs == s_t
-            return {k: v[keep] for k, v in cols.items()}
         cols[s_var] = subs
         return cols
 
 
 def join_order(patterns) -> list:
-    """A constant-object pattern first (the smallest starting set), then
-    each next pattern in the given order that shares a bound variable."""
+    """A constant-object pattern first (the smallest starting set), else a
+    constant-subject one; then each next pattern in the given order that
+    shares a bound variable or has a constant subject."""
+    def const(x):
+        return not isinstance(x, tuple)
+
     rest = list(patterns)
-    first = next((t for t in rest if not isinstance(t[2], tuple)), rest[0])
+    first = next((t for t in rest if const(t[2])),
+                 next((t for t in rest if const(t[0])), rest[0]))
     rest.remove(first)
     order, bound = [first], {x[1] for x in (first[0], first[2])
                              if isinstance(x, tuple)}
     while rest:
-        nxt = next(t for t in rest if any(
+        nxt = next(t for t in rest if const(t[0]) or any(
             isinstance(x, tuple) and x[1] in bound for x in (t[0], t[2])))
         rest.remove(nxt)
         order.append(nxt)

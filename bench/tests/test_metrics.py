@@ -31,6 +31,8 @@ def window(trace=TRACE):
     ("idle_share.closed", TRACE, 10.0),
     ("idle_share.closed", None, None),
     ("compiles_in_window", TRACE, 2),
+    ("p95_s.closed", TRACE, 7.0),
+    ("p95_s.closed", None, 7.0),
 ])
 def test_reader(name, trace, want):
     got = harness.read_metric(name, window(trace))
@@ -57,3 +59,13 @@ def test_not_ok_counts_unanswered(status):
     got = harness.check(w, answers=None)
     assert got["unanswered"] == {"value": 4, "limit": 0}
     assert got["wrong_answers"] == {"value": 0, "limit": 0}
+
+
+def test_tail_of_unanswered_is_none():
+    """A request due and not answered ``ok`` has no latency: where one
+    reaches the 95th rank, the tail reader reports nothing."""
+    w = window()
+    w.records[0].status = "error"
+    assert harness.read_metric("p95_s.closed", w) is None
+    w.records[0].status = "ok"
+    assert harness.read_metric("p95_s.closed", w) == pytest.approx(7.0)
